@@ -3,6 +3,8 @@
 jax locks the device count at first init, so multi-shard wall-time
 measurements (the paper's speedup curves) re-exec python with
 ``--xla_force_host_platform_device_count=N`` and return JSON via stdout.
+The child runs with ``JAX_PLATFORMS=cpu``: these are host-device-count
+studies, and a child never contends for an accelerator its parent holds.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ def run_with_devices(n_devices: int, module: str, func: str,
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{REPO}/src:{REPO}:" + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)
     for line in proc.stdout.splitlines():

@@ -422,18 +422,19 @@ TABLES = {
 
 
 def main() -> None:
+    """Run the selected tables; the first table that raises ends the run
+    with its traceback and a non-zero exit."""
+    from repro.perf.cache import enable_compilation_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compilation_cache()
     print("name,us_per_call,derived")
     for name, fn in TABLES.items():
         if args.only and name != args.only:
             continue
-        try:
-            fn(args.quick)
-        except Exception as e:  # noqa: BLE001 — keep the suite running
-            _row(name, -1.0, f"ERROR:{type(e).__name__}:{e}")
+        fn(args.quick)
 
 
 if __name__ == "__main__":

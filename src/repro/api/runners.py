@@ -290,8 +290,7 @@ class ShardMapRunner:
 
     def __post_init__(self):
         if self.mesh is None:
-            from repro.launch.mesh import make_mesh_compat
-            object.__setattr__(self, "mesh", make_mesh_compat(
+            object.__setattr__(self, "mesh", jax.make_mesh(
                 (len(jax.devices()),), (self.axis,)))
 
     @property
@@ -304,10 +303,6 @@ class ShardMapRunner:
         return the stacked per-shard output dict (leading dim r, exactly
         like ``VmapRunner.run_raw``); cached/jitted per (mesh, config
         statics, shapes) unless ``cfg.jit_cache`` is off."""
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh, axis = self.mesh, self.axis
@@ -333,10 +328,10 @@ class ShardMapRunner:
                 lambda st, bd: jax.vmap(lambda l: fn(l, bounds=bd),
                                         axis_name=axis)(st), stacked, b)
             out_specs = jax.tree.map(lambda _: P(axis), out_sds)
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(axis), stacked), P()),
-                out_specs=out_specs, check_rep=False)
+                out_specs=out_specs, check_vma=False)
 
         fp = _cache_fingerprint(cfg)
         rows = int(stacked["key"].shape[1])

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.banded_sim import banded_sim_tiles
-from repro.kernels.fused_band import fused_band_scores
+from repro.kernels.fused_band import NATIVE_BLOCK_ALIGN, fused_band_scores
 from repro.kernels.jaccard_band import jaccard_band_tiles
 from repro.kernels.local_attn import local_attention
 
@@ -21,7 +21,8 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def resolve_block_i(m: int, window: int, block_i: int) -> int:
+def resolve_block_i(m: int, window: int, block_i: int, align: int = 1
+                    ) -> int:
     """Pick the row-block size for a band kernel.
 
     The band kernels require ``window <= block_i`` (each row's whole band
@@ -30,14 +31,17 @@ def resolve_block_i(m: int, window: int, block_i: int) -> int:
     block is too small for the window we grow it back up to ``window`` (the
     caller pads M up to a multiple of the block — safe, padded rows are
     masked).  A window that cannot fit in ``block_i`` at all is a config
-    error, reported actionably instead of tripping the kernel's assert."""
+    error, reported actionably instead of tripping the kernel's assert.
+    The result is rounded up to a multiple of ``align`` (the TPU compiler's
+    block granularity for native kernels)."""
     if window > block_i:
         raise ValueError(
             f"band window={window} exceeds block_i={block_i}; the band "
             f"kernels need window <= block_i (one tile + successor covers "
             f"the whole band).  Raise block_i (VMEM grows as block_i^2) or "
             f"use the scan band engine")
-    return max(min(block_i, m), window)
+    bi = max(min(block_i, m), window)
+    return -(-bi // align) * align
 
 
 def band_from_tiles(tiles: jax.Array, *, window: int,
@@ -99,7 +103,8 @@ def fused_cheap_band(feat: jax.Array, sig: jax.Array, *, window: int,
     (M, 2*block_i) tile intermediate, no host-side gather."""
     interpret = default_interpret() if interpret is None else interpret
     m = feat.shape[0]
-    bi = resolve_block_i(m, window, block_i)
+    bi = resolve_block_i(m, window, block_i,
+                         align=1 if interpret else NATIVE_BLOCK_ALIGN)
     pad = (-m) % bi
     if pad:
         feat = jnp.pad(feat, ((0, pad), (0, 0)))

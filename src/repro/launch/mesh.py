@@ -10,23 +10,17 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: older releases reject the
-    ``axis_types`` kwarg; newer ones default it to Auto — so never pass it."""
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Mesh over whatever devices exist (CPU tests / examples)."""
     n = len(jax.devices())
     assert n % model == 0
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip).

@@ -143,6 +143,11 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         tr = self._tracer
+        if tr.jax_profiler and self.attrs.get("device"):
+            # requested, so a failure raises (before any record exists)
+            import jax
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
         st = tr._thread_state()
         stack = st["stack"]
         parent = stack[-1].index if stack else -1
@@ -151,13 +156,6 @@ class _Span:
         with tr._lock:
             rec.index = len(tr._records)
             tr._records.append(rec)
-        if tr.jax_profiler and self.attrs.get("device"):
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:          # noqa: BLE001 — profiler is optional
-                self._ann = None
         stack.append(rec)
         self._rec = rec
         rec.t0 = time.perf_counter() - tr._epoch   # last: excludes setup

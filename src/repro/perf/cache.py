@@ -28,14 +28,39 @@ it honest:
 
 ``facade.resolve`` snapshots ``CacheStats`` around each run and reports the
 delta as ``ERResult.perf`` (hits / misses / traces / entries).
+
+Across processes, ``enable_compilation_cache`` turns on JAX's persistent
+compilation cache.  Entry points (``chip_smoke.py``, ``benchmarks/run.py``)
+call it; importing the library never does.
 """
 from __future__ import annotations
 
+import os
 from collections import OrderedDict
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Any, Callable, Tuple
 
 import jax
+
+# Default persistent-cache location: a fixed path inside the checkout, so a
+# later run finds what an earlier one cached.
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the only location: JAX reads
+    it itself and nothing is set here.  Otherwise the cache lives at
+    ``REPO_CACHE_DIR``.  Call before the first compilation."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    return path
+
 
 # Executables retained before least-recently-used eviction: enough for many
 # concurrent (variant x engine x shape) working sets, small enough that a

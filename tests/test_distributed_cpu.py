@@ -107,7 +107,7 @@ def test_moe_distributed_matches_single_device():
         rules = Rules(mesh, fsdp=False)
         p = MO.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             y_dist, aux_d, _ = jax.jit(
                 lambda p, x: MO.moe_apply(p, x, cfg, rules=rules))(p, x)
         y_ref, aux_r, _ = MO.moe_apply(p, x, cfg, rules=None)

@@ -7,8 +7,16 @@ report pure cache hits on ``ERResult.perf``; any change to input shape,
 window, or another static config field is a miss that retraces.  Device-
 emitted packed pairs (emit="pairs") must be bit-identical to the host
 band-extraction path across all 3 variants x {vmap, shard_map} x
-{scan, pallas}, and pair_cap overflow is counted, never silent.
+{scan, pallas}, and pair_cap overflow is counted, never silent.  The
+persistent compilation cache lives where JAX_COMPILATION_CACHE_DIR says, or
+at the repo's fixed default.
 """
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -241,3 +249,70 @@ def test_seq_match_tail_padding_parity(ents, bounds):
         ents, bounds, cfg)
     h2, m2, t2 = cache.stats.snapshot()
     assert m2 - m1 == 0 and t2 - t1 == 0 and h2 > h1
+
+
+# -- persistent compilation cache ---------------------------------------------------
+
+
+@pytest.fixture
+def restore_jax_cache_config():
+    """Put JAX's compilation-cache settings back after a test moves them."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_enable_compilation_cache)
+    yield
+    jax.config.update("jax_compilation_cache_dir", before[0])
+    jax.config.update("jax_enable_compilation_cache", before[1])
+    cc.reset_cache()
+
+
+def test_compilation_cache_defaults_to_repo_dir(monkeypatch,
+                                                restore_jax_cache_config):
+    import jax
+    from repro.perf.cache import REPO_CACHE_DIR, enable_compilation_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert REPO_CACHE_DIR == Path(__file__).resolve().parents[1] / \
+        ".jax_cache"
+    assert enable_compilation_cache() == str(REPO_CACHE_DIR)
+    assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+    assert jax.config.jax_enable_compilation_cache
+
+
+def test_compilation_cache_env_dir_is_the_only_location(
+        monkeypatch, tmp_path, restore_jax_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets no directory of
+    its own: JAX reads the variable itself."""
+    import jax
+    from repro.perf.cache import enable_compilation_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compilation_cache_entries_land_in_env_dir(tmp_path):
+    """A fresh process with JAX_COMPILATION_CACHE_DIR writes its entries
+    there and adds none to the repo's default directory."""
+    from repro.perf.cache import REPO_CACHE_DIR
+    count = lambda p: len(os.listdir(p)) if os.path.isdir(p) else 0
+    repo_before = count(REPO_CACHE_DIR)
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from repro.perf.cache import enable_compilation_cache
+        print(enable_compilation_cache())
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.block_until_ready(jax.jit(lambda x: jnp.sin(x) * 3)(
+            jnp.arange(8.0)))
+    """)
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               PYTHONPATH=f"{root / 'src'}:" + os.environ.get("PYTHONPATH",
+                                                              ""))
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip() == str(tmp_path / "cc")
+    assert count(tmp_path / "cc") > 0
+    assert count(REPO_CACHE_DIR) == repo_before
