@@ -248,6 +248,35 @@ class MultiPassResult:
 
 # -- pair extraction (band mask -> host pairs) --------------------------------------
 
+# the leaves of one stacked part that host collection reads, besides the
+# eid vector: the bands or the emitted index buffers, and the accounting
+COLLECTED_FIELDS = ("mask", "match", "mask_idx", "mask_n", "mask_overflow",
+                    "match_idx", "match_n", "match_overflow", "cand_count",
+                    "cand_overflow", "matcher_evals", "pruned")
+
+
+def collected_leaves(out: dict, parts) -> dict:
+    """The subtree of a stacked shard-program output that collection
+    (``variants.collect`` and the runners' accounting) reads: ``load``,
+    ``overflow`` and, per part in ``parts``, the ``eid`` vector and the
+    ``COLLECTED_FIELDS`` it holds.  The payload, keys, validity and scores
+    are left out, so a device-to-host fetch of this subtree moves only
+    what is read."""
+    keep = {"load": out["load"], "overflow": out["overflow"]}
+    for p in parts:
+        if p in out:
+            part = out[p]
+            keep[p] = {k: part[k] for k in COLLECTED_FIELDS if k in part}
+            keep[p]["eid"] = _eid(part)
+    return keep
+
+
+def _eid(part: dict):
+    """A part's (r, M) eid vector: top level (device-emitted pairs and
+    ``collected_leaves``) or inside the part's entities."""
+    return part["eid"] if "eid" in part else part["ents"]["eid"]
+
+
 def packed_pairs_from_idx(part: dict, field: str = "match") -> np.ndarray:
     """Device-emitted packed indices -> deduplicated packed pair array.
 
@@ -256,8 +285,7 @@ def packed_pairs_from_idx(part: dict, field: str = "match") -> np.ndarray:
     and ``<field>_n`` (r,) valid counts (window.emit_band_indices).  Eid
     translation is vectorized: one mask + two fancy gathers + ``np.unique``
     over ~cap slots instead of an O(r*w*M) band scan."""
-    eid = np.asarray(part["eid"] if "eid" in part
-                     else part["ents"]["eid"])            # (r, M)
+    eid = np.asarray(_eid(part))                          # (r, M)
     idx = np.asarray(part[field + "_idx"])                # (r, cap)
     cnt = np.asarray(part[field + "_n"]).reshape(-1)      # (r,)
     m = eid.shape[1]
@@ -284,11 +312,12 @@ def packed_pairs_from_part(part: dict, field: str = "match") -> np.ndarray:
 def packed_pairs_from_band(part: dict, field: str = "match") -> np.ndarray:
     """Vectorized band -> deduplicated packed pair array (the hot host path).
 
-    ``part``: stacked per-shard output dict with ``ents`` (eid: (r, M)) and a
-    boolean band ``field`` of shape (r, w-1, M); band[s, d-1, i] pairs slot i
-    with slot i+d of shard s.  One batched nonzero + pack + ``np.unique`` —
-    no Python pair objects anywhere on the path."""
-    eid = np.asarray(part["ents"]["eid"])                 # (r, M)
+    ``part``: stacked per-shard output dict with ``eid`` (r, M), at its top
+    level or under ``ents``, and a boolean band ``field`` of shape
+    (r, w-1, M); band[s, d-1, i] pairs slot i with slot i+d of shard s.
+    One batched nonzero + pack + ``np.unique`` — no Python pair objects
+    anywhere on the path."""
+    eid = np.asarray(_eid(part))                          # (r, M)
     band = np.asarray(part[field])                        # (r, w-1, M)
     ss, ds, iis = np.nonzero(band)
     if ss.size == 0:

@@ -133,16 +133,18 @@ class PackedOutcome(NamedTuple):
     pruned: int = 0
 
     def to_outcome(self) -> RunnerOutcome:
-        """Materialize the public RunnerOutcome (frozensets of (lo, hi))."""
-        return RunnerOutcome(
-            blocked=RES.packed_to_frozenset(self.blocked),
-            matched=RES.packed_to_frozenset(self.matched),
-            load=self.load, overflow=self.overflow,
-            num_shards=self.num_shards, cand_count=self.cand_count,
-            cand_overflow=self.cand_overflow,
-            matcher_evals=self.matcher_evals,
-            pair_overflow=self.pair_overflow,
-            pruned=self.pruned)
+        """Materialize the public RunnerOutcome (frozensets of (lo, hi)),
+        inside a ``to_outcome`` span."""
+        with OBS.span("to_outcome"):
+            return RunnerOutcome(
+                blocked=RES.packed_to_frozenset(self.blocked),
+                matched=RES.packed_to_frozenset(self.matched),
+                load=self.load, overflow=self.overflow,
+                num_shards=self.num_shards, cand_count=self.cand_count,
+                cand_overflow=self.cand_overflow,
+                matcher_evals=self.matcher_evals,
+                pair_overflow=self.pair_overflow,
+                pruned=self.pruned)
 
 
 @runtime_checkable
@@ -180,19 +182,23 @@ def shard_input(ents: dict, r: int) -> dict:
 def _device_outcome_packed(out: dict, cfg, r: int) -> PackedOutcome:
     """Stacked device output -> PackedOutcome (collection + accounting; the
     shared back half of every device runner's resolve/resolve_packed).
-    Under an active tracer the whole collection runs inside a ``collect``
-    span carrying the device->host transfer bytes and the realized
-    per-shard loads — the Afrati/Ullman communication-cost attribution of
-    DESIGN.md §12."""
+
+    Collection first fetches, in one ``jax.device_get``, the leaves it
+    reads (``results.collected_leaves``: no payload), then works on the
+    host.  Under an active tracer the whole collection runs inside a
+    ``collect`` span carrying the realized per-shard loads, the fetch
+    inside its child ``transfer`` span, and the ``transfer_bytes`` counter
+    adds the bytes fetched — the Afrati/Ullman communication-cost
+    attribution of DESIGN.md §12."""
+    variant = get_variant(cfg.variant)
     sp = OBS.span("collect")
     with sp:
-        if sp.enabled:
-            nbytes = sum(int(getattr(x, "nbytes", 0))
-                         for x in jax.tree.leaves(out))
-            sp.set(transfer_bytes=nbytes)
-            OBS.current_tracer().metrics.counter("transfer_bytes") \
-                .inc(nbytes)
-        variant = get_variant(cfg.variant)
+        tsp = OBS.span("transfer")
+        with tsp:
+            out = jax.device_get(RES.collected_leaves(out, variant.parts))
+            if tsp.enabled:
+                OBS.current_tracer().metrics.counter("transfer_bytes").inc(
+                    sum(x.nbytes for x in jax.tree.leaves(out)))
         col = variant.collect(out)
         load = tuple(int(x) for x in np.asarray(out["load"])[0])
         overflow = int(np.asarray(out["overflow"])[0])

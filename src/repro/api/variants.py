@@ -32,6 +32,7 @@ from repro.core import sn
 from repro.core import srp as S
 from repro.core import window as W
 from repro.api import results as RES
+from repro.obs.scopes import BAND_SELECT, SHUFFLE
 
 _REGISTRY: Dict[str, Type["VariantBase"]] = {}
 
@@ -77,7 +78,11 @@ class VariantBase:
         or shard_map): SRP shuffle + this variant's ``_windows`` step.
         Returns the per-shard output dict (``overflow``, ``load``, one band
         part per ``self.parts``).  ``cap_link`` is the planner-provided
-        shuffle capacity; None derives it from ``cfg.cap_factor``."""
+        shuffle capacity; None derives it from ``cfg.cap_factor``.
+
+        Device stages (``repro.obs.scopes``): the SRP shuffle and the
+        variant's halo or boundary exchange run in ``shuffle``, each band
+        in ``band/select`` (``_band``)."""
         # capacity precedence: planner-provided cap_link (exact, from the
         # ShardPlan) > cfg.cap_factor > full capacity (never overflows)
         cap0 = ents["key"].shape[0]
@@ -90,8 +95,11 @@ class VariantBase:
                 f"shard, but window={cfg.window} exceeds the per-shard "
                 f"buffer of {r * cap_link} slots; reduce window or "
                 f"num_shards, raise cap_factor, or use runner='sequential'")
-        sorted_ents, overflow = S.srp_shard(ents, bounds, r, axis, cap_link)
-        out = {"overflow": overflow, "load": S.local_load(sorted_ents, axis)}
+        with jax.named_scope(SHUFFLE):
+            sorted_ents, overflow = S.srp_shard(ents, bounds, r, axis,
+                                                cap_link)
+            load = S.local_load(sorted_ents, axis)
+        out = {"overflow": overflow, "load": load}
         out.update(self._windows(sorted_ents, r, axis, cfg))
         return out
 
@@ -109,9 +117,14 @@ class VariantBase:
         (``window.emit_band_indices`` — capacity ``cfg.pair_cap``, overflow
         counted) and the part carries only those buffers plus the (M,) eid
         vector for host translation, instead of O(w*M) bands + full payload
-        slices."""
+        slices.
+
+        The band runs inside the ``band/select`` device scope, and the
+        engine opens ``band/align``, ``band/cheap`` and ``band/expensive``
+        inside it (``repro.obs.scopes``)."""
         engine = W.get_band_engine(getattr(cfg, "band_engine", "scan"))
-        out = engine.band(e, cfg, halo_len=halo_len, mode=mode)
+        with jax.named_scope(BAND_SELECT):
+            out = engine.band(e, cfg, halo_len=halo_len, mode=mode)
         if getattr(cfg, "emit", "band") == "pairs":
             m = e["valid"].shape[0]
             full = (cfg.window - 1) * m
@@ -122,7 +135,9 @@ class VariantBase:
                     "match": cap if bound is None  # bound (pallas cand_cap)
                     else min(cap, bound)}          # shrink its buffer
             for field in ("mask", "match"):
-                emitted = W.emit_band_indices(out.pop(field), caps[field])
+                with jax.named_scope(BAND_SELECT):
+                    emitted = W.emit_band_indices(out.pop(field),
+                                                  caps[field])
                 out.update({f"{field}_idx": emitted["idx"],
                             f"{field}_n": emitted["n"],
                             f"{field}_overflow": emitted["overflow"]})
@@ -201,8 +216,9 @@ class RepSNVariant(VariantBase):
     halo_slices = True
 
     def _windows(self, sorted_ents, r, axis, cfg):
-        combined, hl = R.repsn_combine(sorted_ents, cfg.window, r, axis,
-                                       hops=cfg.hops)
+        with jax.named_scope(SHUFFLE):
+            combined, hl = R.repsn_combine(sorted_ents, cfg.window, r, axis,
+                                           hops=cfg.hops)
         return {"main": self._band(combined, hl, "native", cfg)}
 
 
@@ -215,6 +231,7 @@ class JobSNVariant(VariantBase):
     halo_slices = True
 
     def _windows(self, sorted_ents, r, axis, cfg):
-        group, hl = J.boundary_group(sorted_ents, cfg.window, r, axis)
+        with jax.named_scope(SHUFFLE):
+            group, hl = J.boundary_group(sorted_ents, cfg.window, r, axis)
         return {"main": self._band(sorted_ents, 0, "all", cfg),
                 "boundary": self._band(group, hl, "cross", cfg)}

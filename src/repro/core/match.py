@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.scopes import BAND_CHEAP, BAND_EXPENSIVE
+
 
 # -- primitive similarities (operate on payload slices of paired entities) ----------
 
@@ -148,7 +150,9 @@ class CascadeMatcher:
     combined score drops below the threshold, later matchers are skipped.
 
     ``combined(pa, pb)`` returns (score, evaluated_mask) vectorized over any
-    leading shape."""
+    leading shape.  Each matcher runs inside a device stage scope: the most
+    expensive of two or more in ``band/expensive``, the others in
+    ``band/cheap`` (``repro.obs.scopes``)."""
     matchers: Tuple[Matcher, ...]
     threshold: float = 0.75
 
@@ -162,9 +166,12 @@ class CascadeMatcher:
         remaining = wsum
         evaluated = 0.0
         alive = None
-        for m in ms:
+        for i, m in enumerate(ms):
+            stage = BAND_EXPENSIVE if i == len(ms) - 1 and len(ms) > 1 \
+                else BAND_CHEAP
             if acc is None:
-                s = m(pa, pb)
+                with jax.named_scope(stage):
+                    s = m(pa, pb)
                 acc = m.weight * s
                 alive = jnp.ones_like(s, bool)
             else:
@@ -172,7 +179,9 @@ class CascadeMatcher:
                     # max achievable if every remaining matcher scored 1.0
                     best = (acc + remaining) / wsum
                     alive = alive & (best >= self.threshold)
-                s = jnp.where(alive, m(pa, pb), 0.0)
+                with jax.named_scope(stage):
+                    s = m(pa, pb)
+                s = jnp.where(alive, s, 0.0)
                 acc = acc + m.weight * s
             evaluated = evaluated + (alive.astype(jnp.float32)
                                      if alive is not None else 1.0)

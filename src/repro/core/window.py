@@ -30,6 +30,11 @@ Engines register with ``@register_band_engine("name")``; both return the
 same part dict (``mask``/``match``/``matcher_evals``/``cand_overflow``), so
 variants and runners never branch on the engine.
 
+Device stages (``repro.obs.scopes``): the variants run the band inside
+``band/select``; here the rolls and gathers that put each row beside its
+partner open ``band/align``, the fused kernel ``band/cheap``, and the
+matchers their own stage (``CascadeMatcher.combined``).
+
 The halo/seam convention generalizes beyond shard boundaries: the same
 ``[halo | native]`` layout that closes partition seams (RepSN) closes the
 CHUNK seams of out-of-core streaming — ``repro.stream`` prepends the w-1
@@ -47,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.match import CascadeMatcher
+from repro.obs.scopes import BAND_ALIGN, BAND_CHEAP
 
 # epsilon guard on the cascade gate: the fused kernel's cheap scores can
 # differ from the jnp oracle by reduction-order ulps; widening the gate by
@@ -123,7 +129,8 @@ def band_scores(ents: dict, w: int, matcher: CascadeMatcher, *,
     weff = payload.get("_weff")      # adaptive per-entity windows, if riding
 
     def step(_, d):
-        rolled = {k: jnp.roll(v, -d, axis=0) for k, v in payload.items()}
+        with jax.named_scope(BAND_ALIGN):
+            rolled = {k: jnp.roll(v, -d, axis=0) for k, v in payload.items()}
         score, _ = matcher.combined(payload, rolled, skip=skip)
         ok = _pair_mask(valid, d, halo_len=halo_len, mode=mode, weff=weff)
         return None, (jnp.where(ok, score, 0.0), ok)
@@ -213,11 +220,15 @@ def cheap_band_jnp(payload: dict, split: "CascadeSplit",
     def step(_, d):
         part = jnp.float32(0.0)
         if feat is not None:
-            part = part + split.w_cos * cosine_sim(
-                feat, jnp.roll(feat, -d, axis=0))
+            with jax.named_scope(BAND_ALIGN):
+                partner = jnp.roll(feat, -d, axis=0)
+            with jax.named_scope(BAND_CHEAP):
+                part = part + split.w_cos * cosine_sim(feat, partner)
         if sig is not None:
-            part = part + split.w_jac * jaccard_sig(
-                sig, jnp.roll(sig, -d, axis=0))
+            with jax.named_scope(BAND_ALIGN):
+                partner = jnp.roll(sig, -d, axis=0)
+            with jax.named_scope(BAND_CHEAP):
+                part = part + split.w_jac * jaccard_sig(sig, partner)
         return None, part
 
     _, rows = jax.lax.scan(step, None, jnp.arange(1, w, dtype=jnp.int32))
@@ -230,8 +241,9 @@ def score_candidates(ents: dict, cand_i, cand_d, cand_valid,
     the real-FLOP realization of the paper's skip optimization."""
     j = cand_i + cand_d
     j = jnp.minimum(j, ents["valid"].shape[0] - 1)
-    pa = {k: v[cand_i] for k, v in ents["payload"].items()}
-    pb = {k: v[j] for k, v in ents["payload"].items()}
+    with jax.named_scope(BAND_ALIGN):
+        pa = {k: v[cand_i] for k, v in ents["payload"].items()}
+        pb = {k: v[j] for k, v in ents["payload"].items()}
     score, _ = matcher.combined(pa, pb, skip=False)
     return jnp.where(cand_valid, score, 0.0)
 
@@ -501,11 +513,12 @@ class PallasBandEngine(BandEngine):
                 jnp.zeros((m, 1), jnp.float32)
             sig = payload[split.sig_field] if split.sig_field else \
                 jnp.zeros((m, 1), jnp.uint32)
-            cheap = ops.fused_cheap_band(
-                feat, sig, window=w - 1, w_cos=split.w_cos,
-                w_jac=split.w_jac, block_i=cfg.band_block,
-                interpret=cfg.band_interpret)
-            cheap_rows = cheap.T
+            with jax.named_scope(BAND_CHEAP):
+                cheap = ops.fused_cheap_band(
+                    feat, sig, window=w - 1, w_cos=split.w_cos,
+                    w_jac=split.w_jac, block_i=cfg.band_block,
+                    interpret=cfg.band_interpret)
+                cheap_rows = cheap.T
         gate = (cheap_rows >= split.tau_partial) & mask     # (w-1, M)
 
         cand_cap = cfg.cand_cap or 0   # None (unresolved auto) acts like 0

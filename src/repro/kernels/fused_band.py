@@ -134,6 +134,7 @@ def fused_band_scores(feat: jax.Array, sig: jax.Array, *, window: int,
         out_specs=pl.BlockSpec((block_i, window), cur),
         out_shape=jax.ShapeDtypeStruct((m, window), jnp.float32),
         interpret=interpret,
+        name="fused_cheap_band",   # fixed: device profiles show it so
     )(_reverse_blocks(feat, block_i), feat, feat,
       _reverse_blocks(sig, block_i), sig, sig)
     return _reverse_blocks(band, block_i)

@@ -19,6 +19,17 @@ One observability substrate for the whole pipeline:
                               five legacy stats types unified behind
                               ``metrics()`` (``pack_stats``/
                               ``unpack_stats`` round-trip them losslessly)
+  * ``scopes``                the device stage names of the shard program
+                              (``shuffle``, ``band/align``, ``band/cheap``,
+                              ``band/expensive``, ``band/select``): the
+                              ``jax.named_scope``s a device profile carries
+                              on every operation
+
+A device resolve records, under its ``attempt`` span: ``shard_program``
+(the device program, blocked on when traced), ``collect`` with its child
+``transfer`` (the one device-to-host fetch of the leaves collection reads;
+the ``transfer_bytes`` counter counts exactly those bytes), and
+``to_outcome`` (the public frozensets built from the packed pairs).
 
 Every module here is a leaf (stdlib + numpy only at import time), so the
 instrumented subsystems — ``repro.api``, ``repro.stream``, ``repro.serve``,
