@@ -91,10 +91,12 @@ class ERConfig:
       return_scores  keep band scores in raw runner output
 
     Band engine (core/window.py — how each shard's window band is evaluated):
-      band_engine   "scan" (w-1 shifted full-matcher passes; reference
-                    oracle) | "pallas" (fused cheap-band kernel -> cumsum
-                    candidate compaction -> expensive matcher on survivors
-                    only: the §5.1 cascade with real FLOP savings)
+      band_engine   "scan" (w-1 shifted passes of the cheap matchers,
+                    then the last matcher on the skip rule's survivors
+                    only, packed into a band-sized buffer and scored in
+                    chunks: no capacity) | "pallas" (fused cheap-band
+                    kernel -> cumsum candidate compaction into cand_cap
+                    -> the full cascade on survivors)
       band_block    Pallas row-block size Bi (band width w-1 must fit:
                     w-1 <= band_block; VMEM grows as band_block^2)
       cand_cap      per-shard survivor capacity of the cascade compaction;

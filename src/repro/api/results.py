@@ -156,9 +156,9 @@ class BlockingResult:
     runner: str
     window: int
     num_shards: int
-    cand_count: Tuple[int, ...] = ()  # per-shard gate survivors (pallas)
+    cand_count: Tuple[int, ...] = ()  # per-shard cascade-gate survivors
     cand_overflow: int = 0          # cascade survivors dropped by cand_cap
-    matcher_evals: int = 0          # full-cascade evaluations actually run
+    matcher_evals: int = 0          # last-matcher evaluations actually run
     pair_overflow: int = 0          # emitted pair-index slots dropped by
     #                                 pair_cap (emit="pairs"; can lose
     #                                 blocked pairs AND matches — counted,

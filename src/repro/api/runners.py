@@ -86,15 +86,17 @@ def _cache_fingerprint(cfg):
 class RunnerOutcome(NamedTuple):
     """What every runner returns: host pair sets + accounting.
 
-    ``cand_count`` is the PER-SHARD cascade-gate survivors kept (pallas
-    band engine; zeros for scan) — per-shard like ``load`` so the
-    DESIGN.md §6 cand_cap sizing rule (cap ~1.25x the busiest shard) is
-    executable from the public result.  ``cand_overflow`` counts survivors
-    dropped by ``cfg.cand_cap`` (may lose MATCHES, never blocked pairs);
-    ``matcher_evals`` counts full-cascade evaluations ACTUALLY run — one
-    per band slot for scan, one per cand_cap buffer slot for pallas (static
-    shapes: a finite cand_cap is the §5.1 FLOP lever, reported honestly so
-    benchmarks can verify it)."""
+    ``cand_count`` is the PER-SHARD cascade-gate survivors kept (zeros
+    for a one-matcher cascade, which has no gate) — per-shard like
+    ``load`` so the DESIGN.md §6 cand_cap sizing rule (cap ~1.25x the
+    busiest shard) is executable from the public result.
+    ``cand_overflow`` counts survivors dropped by ``cfg.cand_cap`` (pallas;
+    may lose MATCHES, never blocked pairs); ``matcher_evals`` counts
+    evaluations of the cascade's last matcher ACTUALLY run — the scan
+    engine's survivors in whole chunks, one per band slot for a
+    one-matcher cascade, one per cand_cap buffer slot for pallas (static
+    shapes, reported honestly so benchmarks can verify the §5.1 FLOP
+    cut)."""
     blocked: FrozenSet[Pair]
     matched: FrozenSet[Pair]
     load: Tuple[int, ...]
@@ -219,6 +221,10 @@ def _device_outcome_packed(out: dict, cfg, r: int) -> PackedOutcome:
                         int(np.asarray(out[p]["match_overflow"]).sum())
         if sp.enabled:
             sp.set(load=load)
+            # the skip rule's engagement: survivors over blocked slots
+            metrics = OBS.current_tracer().metrics
+            metrics.counter("band.survivors").inc(int(cand_count.sum()))
+            metrics.counter("band.expensive_evals").inc(matcher_evals)
     return PackedOutcome(blocked=col.blocked, matched=col.matched,
                          load=load, overflow=overflow, num_shards=r,
                          cand_count=tuple(int(c) for c in cand_count),

@@ -10,9 +10,10 @@ TPU adaptation (DESIGN.md §2): entities carry
   * "text": padded byte strings  -> exact edit distance (expensive matcher)
 
 ``CascadeMatcher`` reproduces the skip optimization: the cheap similarity
-gates the expensive one (vectorized as a candidate mask; the pair-compaction
-path in pipeline.py turns that mask into real FLOP savings, and the Pallas
-band kernels implement the cheap stage at MXU rate).
+gates the expensive one.  Vectorized, ``combined`` applies the rule as a
+mask; the band engines (core/window.py) turn it into real FLOP savings by
+compacting the gate's survivors and running the last matcher on them only
+(the Pallas engine also runs the cheap stage as a fused kernel).
 """
 from __future__ import annotations
 
@@ -159,34 +160,68 @@ class CascadeMatcher:
     def ordered(self):
         return tuple(sorted(self.matchers, key=lambda m: m.cost))
 
-    def combined(self, pa, pb, *, skip: bool = True):
+    @property
+    def weight_sum(self) -> float:
+        """The matchers' total weight, which normalises the score."""
+        return sum(m.weight for m in self.ordered())
+
+    def _skip_rule(self, acc, alive, remaining: float, skip: bool):
+        """The pairs still alive once the best achievable combined score,
+        every remaining matcher scoring 1.0, is known: ``alive`` and
+        ``(acc + remaining) / wsum >= threshold`` (unchanged without
+        ``skip``)."""
+        if not skip:
+            return alive
+        return alive & ((acc + remaining) / self.weight_sum
+                        >= self.threshold)
+
+    def prefix(self, pa, pb, *, skip: bool = True):
+        """Every matcher but the last, cheap to expensive, in ``band/cheap``.
+
+        Returns ``(acc, alive, evaluated)``: the weighted partial sum (None
+        for a one-matcher cascade), the skip rule's verdict on the last
+        matcher (the pairs it could still lift to the threshold; all True
+        without ``skip``) and the evaluations counted so far.  ``combined``
+        is ``prefix`` then ``finish``; the scan band engine runs the last
+        matcher only where ``alive`` holds (``core/window.py``)."""
         ms = self.ordered()
-        wsum = sum(m.weight for m in ms)
-        acc = None
-        remaining = wsum
+        acc = alive = None
+        remaining = self.weight_sum
         evaluated = 0.0
-        alive = None
-        for i, m in enumerate(ms):
-            stage = BAND_EXPENSIVE if i == len(ms) - 1 and len(ms) > 1 \
-                else BAND_CHEAP
+        for m in ms[:-1]:
+            if acc is not None:
+                alive = self._skip_rule(acc, alive, remaining, skip)
+            with jax.named_scope(BAND_CHEAP):
+                s = m(pa, pb)
             if acc is None:
-                with jax.named_scope(stage):
-                    s = m(pa, pb)
                 acc = m.weight * s
                 alive = jnp.ones_like(s, bool)
             else:
-                if skip:
-                    # max achievable if every remaining matcher scored 1.0
-                    best = (acc + remaining) / wsum
-                    alive = alive & (best >= self.threshold)
-                with jax.named_scope(stage):
-                    s = m(pa, pb)
-                s = jnp.where(alive, s, 0.0)
-                acc = acc + m.weight * s
-            evaluated = evaluated + (alive.astype(jnp.float32)
-                                     if alive is not None else 1.0)
+                acc = acc + m.weight * jnp.where(alive, s, 0.0)
+            evaluated = evaluated + alive.astype(jnp.float32)
             remaining -= m.weight
-        return acc / wsum, evaluated
+        if acc is not None:
+            alive = self._skip_rule(acc, alive, remaining, skip)
+        return acc, alive, evaluated
+
+    def finish(self, acc, alive, s):
+        """The combined score from ``prefix``'s ``acc`` and the last
+        matcher's score ``s``, which counts only where ``alive``."""
+        last = self.ordered()[-1]
+        if acc is None:
+            return last.weight * s / self.weight_sum
+        return (acc + last.weight * jnp.where(alive, s, 0.0)) \
+            / self.weight_sum
+
+    def combined(self, pa, pb, *, skip: bool = True):
+        acc, alive, evaluated = self.prefix(pa, pb, skip=skip)
+        stage = BAND_CHEAP if acc is None else BAND_EXPENSIVE
+        with jax.named_scope(stage):
+            s = self.ordered()[-1](pa, pb)
+        if alive is None:
+            alive = jnp.ones_like(s, bool)
+        return self.finish(acc, alive, s), \
+            evaluated + alive.astype(jnp.float32)
 
     def matches(self, pa, pb, *, skip: bool = True):
         score, _ = self.combined(pa, pb, skip=skip)

@@ -10,9 +10,13 @@ Band evaluation is a pluggable seam — a **BandEngine** selected by
 ``ERConfig.band_engine`` and used by every variant's ``_band`` hook:
 
   * ``scan``    (ScanBandEngine) pure-JAX scan over distances: w-1 shifted
-                full-payload passes through ``CascadeMatcher.combined``.
-                Memory-safe reference oracle; the §5.1 "skip" is a
-                ``jnp.where`` that still computes both branches.
+                payload passes score every slot with every matcher but
+                the last (``CascadeMatcher.prefix``); the §5.1 skip rule's
+                survivors among the blocked slots are compacted into a
+                band-sized buffer (never overflows) and the last matcher
+                scores them in chunks (``score_survivors``), so the
+                expensive matcher runs only where the threshold is still
+                reachable.  No capacity, O(M * F) live payload.
   * ``pallas``  (PallasBandEngine) the paper's §5.1 two-stage cascade with
                 REAL FLOP savings: a fused Pallas kernel
                 (kernels/fused_band.py) evaluates the cheap matchers for the
@@ -27,8 +31,8 @@ Band evaluation is a pluggable seam — a **BandEngine** selected by
                 the full jnp cascade.
 
 Engines register with ``@register_band_engine("name")``; both return the
-same part dict (``mask``/``match``/``matcher_evals``/``cand_overflow``), so
-variants and runners never branch on the engine.
+same part dict (``mask``/``match``/``matcher_evals``/``cand_count``/
+``cand_overflow``), so variants and runners never branch on the engine.
 
 Device stages (``repro.obs.scopes``): the variants run the band inside
 ``band/select``; here the rolls and gathers that put each row beside its
@@ -52,13 +56,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.match import CascadeMatcher
-from repro.obs.scopes import BAND_ALIGN, BAND_CHEAP
+from repro.obs.scopes import BAND_ALIGN, BAND_CHEAP, BAND_EXPENSIVE
 
 # epsilon guard on the cascade gate: the fused kernel's cheap scores can
 # differ from the jnp oracle by reduction-order ulps; widening the gate by
 # GATE_EPS (in normalized-score units) keeps every pair the scan engine
 # could accept, and extra survivors are exactly rescored anyway.
 GATE_EPS = 1e-5
+
+# gate survivors per shard that the scan engine's last matcher scores in
+# one pass of its loop (``score_survivors``); chosen on a v5e (PERF.md)
+SURVIVOR_CHUNK = 4096
 
 
 def _pair_mask(valid: jax.Array, d: jax.Array, *, halo_len: int,
@@ -248,6 +256,68 @@ def score_candidates(ents: dict, cand_i, cand_d, cand_valid,
     return jnp.where(cand_valid, score, 0.0)
 
 
+def prefix_band(payload: dict, matcher: CascadeMatcher, w: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(acc, alive), each (w-1, M): ``matcher.prefix`` (every matcher but
+    the last, and the skip rule) of the distance-d pairs (i, i+d) in row
+    d-1, by a scan over distances of rolled payload views."""
+    fields = {m.field for m in matcher.ordered()[:-1]}
+
+    def step(_, d):
+        with jax.named_scope(BAND_ALIGN):
+            rolled = {f: jnp.roll(payload[f], -d, axis=0) for f in fields}
+        acc, alive, _ = matcher.prefix(payload, rolled)
+        return None, (acc, alive)
+
+    _, rows = jax.lax.scan(step, None, jnp.arange(1, w, dtype=jnp.int32))
+    return rows
+
+
+def score_survivors(payload: dict, matcher: CascadeMatcher, acc: jax.Array,
+                    gate: jax.Array) -> Tuple[jax.Array, jax.Array,
+                                              jax.Array]:
+    """Run the cascade's last matcher on the True slots of ``gate`` only.
+
+    ``acc`` (w-1, M) is ``prefix_band``'s partial sum.  The gate's slots
+    are packed in band order into a buffer the size of the band
+    (``compact_flat``, so nothing overflows), and a ``while_loop`` scores
+    them ``SURVIVOR_CHUNK`` at a time while any remain, scattering each
+    chunk's scores into the band; under vmap it runs until the busiest
+    shard is done.  Returns (scores (w-1, M), survivors, evals): a
+    survivor's score is ``finish(acc, True, s)``, every other slot's
+    ``finish(acc, False, .)``, what ``combined`` gives a pair the skip
+    rule dropped; ``evals`` counts the buffer positions scored."""
+    rows, m = gate.shape
+    slots = rows * m
+    c = min(SURVIVOR_CHUNK, slots)
+    idx, n, _ = compact_flat(gate, slots)
+    last = matcher.ordered()[-1]
+    field = payload[last.field]
+    acc_flat = acc.reshape(-1)
+
+    def body(carry):
+        k, scores = carry
+        # the last chunk is clamped to end at the buffer's end, so it stays
+        # in bounds; the survivors it scores again get the same result
+        start = jnp.minimum(k * c, slots - c)
+        flat = jax.lax.dynamic_slice(idx, (start,), (c,))
+        i = flat % m
+        j = jnp.minimum(i + flat // m + 1, m - 1)
+        with jax.named_scope(BAND_ALIGN):
+            pa, pb = {last.field: field[i]}, {last.field: field[j]}
+            part = acc_flat[flat]
+        with jax.named_scope(BAND_EXPENSIVE):
+            s = last(pa, pb)
+        live = start + jnp.arange(c, dtype=jnp.int32) < n
+        return k + 1, scores.at[jnp.where(live, flat, slots)].set(
+            matcher.finish(part, True, s), mode="drop")
+
+    k, scores = jax.lax.while_loop(
+        lambda carry: carry[0] * c < n, body,
+        (jnp.int32(0), matcher.finish(acc_flat, False, 0.0)))
+    return scores.reshape(rows, m), n, jnp.minimum(k * c, slots)
+
+
 def prune_low_evidence(payload: dict, matcher: CascadeMatcher, w: int,
                        mask: jax.Array, threshold: float
                        ) -> Tuple[jax.Array, jax.Array]:
@@ -352,13 +422,15 @@ class BandEngine:
 
       mask           (w-1, M) bool   blocked (candidate) pairs
       match          (w-1, M) bool   matcher-accepted pairs
-      matcher_evals  ()       int32  full-cascade evaluations ACTUALLY run
-                                     (static-shape honest: the pallas
-                                     engine's expensive stage scores its
-                                     whole cand_cap buffer, so a finite
-                                     cand_cap is what buys the FLOP cut)
-      cand_count     ()       int32  cascade-gate survivors kept (pallas;
-                                     0 for scan — no gate)
+      matcher_evals  ()       int32  evaluations of the cascade's last
+                                     (most expensive) matcher ACTUALLY run
+                                     (static-shape honest: scan scores its
+                                     survivors in whole chunks; the pallas
+                                     engine scores its whole cand_cap
+                                     buffer, so there a finite cand_cap is
+                                     what buys the FLOP cut)
+      cand_count     ()       int32  cascade-gate survivors kept (0 for a
+                                     one-matcher cascade — no gate)
       cand_overflow  ()       int32  gate survivors dropped by cand_cap
       scores         (w-1, M) f32    only when cfg.return_scores
     """
@@ -384,26 +456,37 @@ class BandEngine:
 
 @register_band_engine("scan")
 class ScanBandEngine(BandEngine):
-    """Reference oracle: w-1 shifted full-payload passes.  The cascade skip
-    is a ``jnp.where`` — both branches are computed, so every band slot
-    costs one full matcher evaluation."""
+    """Pure-JAX engine and the default: a scan over the w-1 distances
+    scores every slot with every matcher but the last (``prefix_band``);
+    the last matcher then runs only on the blocked slots the skip rule
+    keeps (``score_survivors``), so every blocked pair gets exactly the
+    score ``CascadeMatcher.combined`` gives it.  A one-matcher cascade
+    cannot skip: it scores every slot in the scan."""
 
     def band(self, ents: dict, cfg, *, halo_len: int, mode: str) -> dict:
-        scores, mask = band_scores(ents, cfg.window, cfg.matcher,
-                                   halo_len=halo_len, mode=mode)
-        src = self._src(ents, cfg)
-        if src is not None:
-            mask = mask & cross_source_rows(src, cfg.window)
+        w, matcher = cfg.window, cfg.matcher
+        payload = ents["payload"]
+        m = ents["valid"].shape[0]
+        mask = band_mask(ents["valid"], w, halo_len=halo_len, mode=mode,
+                         src=self._src(ents, cfg),
+                         weff=payload.get("_weff"))
         pruned = jnp.int32(0)
         if getattr(cfg, "prune_policy", "off") == "evidence":
-            mask, pruned = prune_low_evidence(
-                ents["payload"], cfg.matcher, cfg.window, mask,
-                cfg.prune_threshold)
-        match = (scores >= cfg.matcher.threshold) & mask
-        m = ents["valid"].shape[0]
-        out = {"mask": mask, "match": match,
-               "matcher_evals": jnp.int32((cfg.window - 1) * m),
-               "cand_count": jnp.int32(0),
+            mask, pruned = prune_low_evidence(payload, matcher, w, mask,
+                                              cfg.prune_threshold)
+        if len(matcher.matchers) < 2:
+            scores, _ = band_scores(ents, w, matcher, halo_len=halo_len,
+                                    mode=mode)
+            survivors, evals = jnp.int32(0), jnp.int32((w - 1) * m)
+        else:
+            acc, alive = prefix_band(payload, matcher, w)
+            scores, survivors, evals = score_survivors(
+                payload, matcher, acc, alive & mask)
+        scores = jnp.where(mask, scores, 0.0)
+        out = {"mask": mask,
+               "match": (scores >= matcher.threshold) & mask,
+               "matcher_evals": evals.astype(jnp.int32),
+               "cand_count": survivors.astype(jnp.int32),
                "cand_overflow": jnp.int32(0),
                "pruned": pruned}
         if cfg.return_scores:
@@ -428,7 +511,7 @@ def split_cascade(matcher: CascadeMatcher,
     """Split the cost-ordered cascade into a kernel-supported cheap prefix
     (one cosine field + one jaccard field, in cost order) and the remainder.
     Returns None when the FIRST matcher is unsupported (no cheap stage — the
-    pallas engine then falls back to the scan oracle)."""
+    pallas engine then falls back to the scan engine)."""
     w_cos = w_jac = 0.0
     feat_field = sig_field = None
     prefix_w = 0.0
@@ -474,7 +557,7 @@ class PallasBandEngine(BandEngine):
         """Accepted matches are scattered from the cand_cap buffer, so a
         finite cand_cap bounds the match band's True count exactly — the
         emitted match index buffer never needs more slots (unless the
-        cascade falls back to the scan oracle, where no such bound holds)."""
+        cascade falls back to the scan engine, where no such bound holds)."""
         cand_cap = cfg.cand_cap or 0   # None (unresolved auto) acts like 0
         if cand_cap > 0 and \
                 split_cascade(cfg.matcher, ents["payload"]) is not None:

@@ -9,7 +9,8 @@ natively on TPU).
 
 Also covered: the cand_cap capacity model (overflow counted, matches-only
 losses), the cumsum compaction primitive, and the §5.1 FLOP claim
-(matcher_evals(pallas) == compacted candidates <= band slots == scan).
+(survivors <= matcher_evals <= each engine's buffer: pallas's cand_cap
+buffer, the scan engine's band).
 """
 import numpy as np
 import pytest
@@ -45,10 +46,11 @@ def _cfg(**kw):
 
 @pytest.mark.parametrize("variant", ["srp", "repsn", "jobsn"])
 def test_vmap_parity_all_variants(ents, bounds, variant):
-    """Acceptance: identical blocked/matched sets, and — with a finite
-    cand_cap sized above the survivor count — the pallas engine's
-    expensive-matcher evaluations (its cand_cap buffer) stay well under the
-    scan engine's one-per-band-slot cost."""
+    """Acceptance: identical blocked/matched sets, and each engine's
+    expensive-matcher evaluations lie between its own gate survivors and
+    its own buffer: pallas scores its cand_cap buffer (sized above the
+    survivor count, well under the band), scan its survivors in whole
+    chunks, at most one per (w-1, M) band slot."""
     cfg = _cfg(variant=variant, runner="vmap")
     scan = api.resolve(ents, cfg, bounds=bounds)
     pal = api.resolve(ents, cfg.with_(band_engine="pallas", cand_cap=256),
@@ -56,13 +58,18 @@ def test_vmap_parity_all_variants(ents, bounds, variant):
     assert pal.blocking.pairs == scan.blocking.pairs
     assert pal.matches == scan.matches
     assert pal.blocking.cand_overflow == 0
+    raw = api.VmapRunner(R).run_raw(ents, bounds, cfg)
+    masks = [np.asarray(raw[p]["mask"]) for p in ("main", "boundary")
+             if p in raw]
+    slots = sum(x.size for x in masks)
     # the FLOP lever: the cap-sized buffer, vs every (w-1, M) band slot
-    assert 0 < pal.blocking.matcher_evals < scan.blocking.matcher_evals
+    assert 0 < pal.blocking.matcher_evals < slots
     # every match is a gate survivor, every kept survivor was scored;
     # cand_count is per-shard (the public probe for the cand_cap sizing rule)
-    assert len(pal.blocking.cand_count) == R
-    assert len(pal.matches) <= sum(pal.blocking.cand_count) \
-        <= pal.blocking.matcher_evals
+    for res, buffer in ((pal, 256 * R * len(masks)), (scan, slots)):
+        assert len(res.blocking.cand_count) == R
+        assert len(res.matches) <= sum(res.blocking.cand_count) \
+            <= res.blocking.matcher_evals <= buffer
 
 
 @pytest.mark.parametrize("variant", ["srp", "repsn", "jobsn"])
