@@ -44,7 +44,7 @@ import numpy as np
 from repro import obs as OBS
 from repro.api import linkage as LK
 from repro.api import results as RES
-from repro.api.variants import get_variant
+from repro.api.variants import get_variant, link_capacity
 from repro.balance.planners import as_plan
 from repro.core import entities as E
 from repro.perf import cache as PC
@@ -181,6 +181,36 @@ def shard_input(ents: dict, r: int) -> dict:
         lambda x: x.reshape((r, cap0) + x.shape[1:]), padded)
 
 
+def _exchange_counts(ents: dict, bounds, stacked: dict, variant, cfg,
+                    cap_link) -> dict:
+    """What one run of the shard program sends between shards, from the
+    plan and the shapes on the host (no device work; the valid flags and
+    keys were fetched by the planner):
+
+      ``shuffle.bytes``       the all_to_all's slots bound for another
+                              shard, ``r * (r-1) * cap_link``, times the
+                              bytes of a row after ``_dest`` is stripped
+                              (buffer padding included)
+      ``shuffle.rows_moved``  valid rows whose reducer (the plan's dest)
+                              is not the mapper split holding them
+      ``halo.rows``           rows of the halo or boundary exchange
+                              (``variant.halo_rows``)
+
+    ``ents`` are the records before ``_apply_plan``, ``stacked`` the
+    mapper splits of ``shard_input``."""
+    r, rows = stacked["key"].shape[:2]
+    cap = link_capacity(rows, r, cfg, cap_link)
+    payload = {k: v for k, v in stacked["payload"].items() if k != "_dest"}
+    row_bytes = sum(x.dtype.itemsize * int(np.prod(x.shape[2:]))
+                    for x in jax.tree.leaves(dict(stacked, payload=payload)))
+    dest = as_plan(bounds).assignment(np.asarray(ents["key"]))
+    moved = np.asarray(ents["valid"]) & \
+        (dest != np.arange(dest.shape[0]) // rows)
+    return {"shuffle.bytes": r * (r - 1) * cap * row_bytes,
+            "shuffle.rows_moved": int(moved.sum()),
+            "halo.rows": variant.halo_rows(r, cfg)}
+
+
 def _device_outcome_packed(out: dict, cfg, r: int) -> PackedOutcome:
     """Stacked device output -> PackedOutcome (collection + accounting; the
     shared back half of every device runner's resolve/resolve_packed).
@@ -314,14 +344,29 @@ class ShardMapRunner:
         """Execute the variant's shard program under ``shard_map`` and
         return the stacked per-shard output dict (leading dim r, exactly
         like ``VmapRunner.run_raw``); cached/jitted per (mesh, config
-        statics, shapes) unless ``cfg.jit_cache`` is off."""
-        from jax.sharding import PartitionSpec as P
+        statics, shapes) unless ``cfg.jit_cache`` is off.
+
+        The mapper splits are placed on the mesh, one per device, inside
+        a ``distribute`` span before the program's ``shard_program`` span;
+        when traced, each blocks on its work, ``distribute_bytes`` adds
+        the bytes placed, and ``_exchange_counts`` are added in an
+        ``exchange_counts`` span after ``shard_program``."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh, axis = self.mesh, self.axis
         r = int(mesh.shape[axis])
         variant = get_variant(cfg.variant)
+        records = ents
         ents, b, cap_link = _apply_plan(ents, bounds, r, cfg)
         stacked = shard_input(ents, r)
+        dsp = OBS.span("distribute", device=True, chips=r)
+        with dsp:
+            stacked = jax.device_put(stacked, NamedSharding(mesh, P(axis)))
+            if dsp.enabled:
+                stacked = jax.block_until_ready(stacked)
+                OBS.current_tracer().metrics.counter(
+                    "distribute_bytes").inc(
+                        sum(x.nbytes for x in jax.tree.leaves(stacked)))
         fn = partial(variant.shard_program, r=r, axis=axis, cfg=cfg,
                      cap_link=cap_link)
 
@@ -336,9 +381,14 @@ class ShardMapRunner:
                 out = fn(local, bounds=bounds_rep)
                 return jax.tree.map(lambda x: jnp.expand_dims(x, 0), out)
 
+            # shapes alone: the placed input's mesh sharding means nothing
+            # to the vmap
             out_sds = jax.eval_shape(
                 lambda st, bd: jax.vmap(lambda l: fn(l, bounds=bd),
-                                        axis_name=axis)(st), stacked, b)
+                                        axis_name=axis)(st),
+                *jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape,
+                                                             x.dtype),
+                              (stacked, b)))
             out_specs = jax.tree.map(lambda _: P(axis), out_sds)
             return jax.shard_map(
                 body, mesh=mesh,
@@ -360,6 +410,15 @@ class ShardMapRunner:
                 out = call(stacked, b)
             if sp.enabled:
                 out = jax.block_until_ready(out)  # see VmapRunner.run_raw
+        if sp.enabled:
+            # host bookkeeping in a span of its own, so neither the
+            # program's span nor the attempt's self time holds it
+            with OBS.span("exchange_counts"):
+                metrics = OBS.current_tracer().metrics
+                counts = _exchange_counts(records, bounds, stacked, variant,
+                                          cfg, cap_link)
+                for name, v in counts.items():
+                    metrics.counter(name).inc(v)
         return out
 
     def resolve(self, ents: dict, bounds, cfg) -> RunnerOutcome:

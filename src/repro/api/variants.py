@@ -32,7 +32,8 @@ from repro.core import sn
 from repro.core import srp as S
 from repro.core import window as W
 from repro.api import results as RES
-from repro.obs.scopes import BAND_SELECT, SHUFFLE
+from repro.obs.scopes import (BAND_SELECT, SHUFFLE, SHUFFLE_EXCHANGE,
+                              SHUFFLE_HALO)
 
 _REGISTRY: Dict[str, Type["VariantBase"]] = {}
 
@@ -61,6 +62,17 @@ def available_variants() -> Tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
+def link_capacity(cap0: int, r: int, cfg, cap_link: int = None) -> int:
+    """The SRP shuffle's per-(mapper, destination) bucket capacity for
+    mapper shards of ``cap0`` rows.  Precedence: the planner-provided
+    ``cap_link`` (exact, from the ShardPlan) > ``cfg.cap_factor`` > full
+    capacity (never overflows)."""
+    if cap_link is not None:
+        return cap_link
+    return cap0 if cfg.cap_factor <= 0 else \
+        max(1, int(np.ceil(cap0 * cfg.cap_factor / r)))
+
+
 class VariantBase:
     """Shared SRP front-end + band evaluation; subclasses add the variant's
     boundary-handling step."""
@@ -81,14 +93,12 @@ class VariantBase:
         shuffle capacity; None derives it from ``cfg.cap_factor``.
 
         Device stages (``repro.obs.scopes``): the SRP shuffle and the
-        variant's halo or boundary exchange run in ``shuffle``, each band
-        in ``band/select`` (``_band``)."""
-        # capacity precedence: planner-provided cap_link (exact, from the
-        # ShardPlan) > cfg.cap_factor > full capacity (never overflows)
-        cap0 = ents["key"].shape[0]
-        if cap_link is None:
-            cap_link = cap0 if cfg.cap_factor <= 0 else \
-                max(1, int(np.ceil(cap0 * cfg.cap_factor / r)))
+        variant's halo or boundary exchange run in ``shuffle`` (its
+        sub-stages ``shuffle/route``, ``shuffle/exchange`` and
+        ``shuffle/sort`` in ``srp.srp_shard``, the load ``all_gather`` in
+        ``shuffle/exchange``, the halo or boundary group in
+        ``shuffle/halo``), each band in ``band/select`` (``_band``)."""
+        cap_link = link_capacity(ents["key"].shape[0], r, cfg, cap_link)
         if self.halo_slices and cfg.window - 1 > r * cap_link:
             raise ValueError(
                 f"variant {self.name!r} slices w-1 boundary slots per "
@@ -98,13 +108,20 @@ class VariantBase:
         with jax.named_scope(SHUFFLE):
             sorted_ents, overflow = S.srp_shard(ents, bounds, r, axis,
                                                 cap_link)
-            load = S.local_load(sorted_ents, axis)
+            with jax.named_scope(SHUFFLE_EXCHANGE):
+                load = S.local_load(sorted_ents, axis)
         out = {"overflow": overflow, "load": load}
         out.update(self._windows(sorted_ents, r, axis, cfg))
         return out
 
     def _windows(self, sorted_ents: dict, r: int, axis: str, cfg) -> dict:
         raise NotImplementedError
+
+    def halo_rows(self, r: int, cfg) -> int:
+        """Rows the variant's halo or boundary exchange sends between
+        shards in one run (the wrapped ring edge, invalidated, not
+        counted)."""
+        return 0
 
     def _band(self, e: dict, halo_len: int, mode: str, cfg) -> dict:
         """Evaluate this part's window band with the configured BandEngine
@@ -216,10 +233,14 @@ class RepSNVariant(VariantBase):
     halo_slices = True
 
     def _windows(self, sorted_ents, r, axis, cfg):
-        with jax.named_scope(SHUFFLE):
+        with jax.named_scope(SHUFFLE), jax.named_scope(SHUFFLE_HALO):
             combined, hl = R.repsn_combine(sorted_ents, cfg.window, r, axis,
                                            hops=cfg.hops)
         return {"main": self._band(combined, hl, "native", cfg)}
+
+    def halo_rows(self, r, cfg):
+        """w-1 rows per hop over each of the r-1 forward ring edges."""
+        return (r - 1) * (cfg.window - 1) * cfg.hops
 
 
 @register_variant("jobsn")
@@ -231,7 +252,11 @@ class JobSNVariant(VariantBase):
     halo_slices = True
 
     def _windows(self, sorted_ents, r, axis, cfg):
-        with jax.named_scope(SHUFFLE):
+        with jax.named_scope(SHUFFLE), jax.named_scope(SHUFFLE_HALO):
             group, hl = J.boundary_group(sorted_ents, cfg.window, r, axis)
         return {"main": self._band(sorted_ents, 0, "all", cfg),
                 "boundary": self._band(group, hl, "cross", cfg)}
+
+    def halo_rows(self, r, cfg):
+        """Each successor's first w-1 rows, over the r-1 backward edges."""
+        return (r - 1) * (cfg.window - 1)

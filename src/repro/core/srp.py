@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core import entities as E
 from repro.core import partition as P
+from repro.obs.scopes import SHUFFLE_EXCHANGE, SHUFFLE_ROUTE, SHUFFLE_SORT
 
 
 def bucketize(ents: dict, dest: jax.Array, r: int,
@@ -82,18 +83,27 @@ def srp_shard(ents: dict, bounds: jax.Array, r: int, axis: str,
     reduce-partition invariant — and every downstream window/halo step —
     holds unchanged.  The tag is consumed map-side and stripped before the
     shuffle (nothing reads it after routing; keeping it would waste
-    all_to_all bandwidth and halo-permute bytes)."""
-    dest = ents["payload"].get("_dest")
-    if dest is None:
-        dest = P.shard_of(bounds, ents["key"])
-    else:
-        ents = dict(ents)
-        ents["payload"] = {k: v for k, v in ents["payload"].items()
-                           if k != "_dest"}
-    buf, overflow = bucketize(ents, dest, r, cap_link)
-    recv = exchange(buf, r, axis)
-    sorted_ents = E.sort_entities(recv)
-    return sorted_ents, jax.lax.psum(overflow, axis)
+    all_to_all bandwidth and halo-permute bytes).
+
+    Device stages (``repro.obs.scopes``): routing and bucketize in
+    ``shuffle/route``, the all_to_all and the overflow ``psum`` in
+    ``shuffle/exchange``, the reduce-side sort in ``shuffle/sort``."""
+    with jax.named_scope(SHUFFLE_ROUTE):
+        dest = ents["payload"].get("_dest")
+        if dest is None:
+            dest = P.shard_of(bounds, ents["key"])
+        else:
+            ents = dict(ents)
+            ents["payload"] = {k: v for k, v in ents["payload"].items()
+                               if k != "_dest"}
+        buf, overflow = bucketize(ents, dest, r, cap_link)
+    with jax.named_scope(SHUFFLE_EXCHANGE):
+        recv = exchange(buf, r, axis)
+    with jax.named_scope(SHUFFLE_SORT):
+        sorted_ents = E.sort_entities(recv)
+    with jax.named_scope(SHUFFLE_EXCHANGE):
+        overflow = jax.lax.psum(overflow, axis)
+    return sorted_ents, overflow
 
 
 def local_load(ents: dict, axis: str) -> jax.Array:

@@ -21,14 +21,20 @@ One observability substrate for the whole pipeline:
                               ``unpack_stats`` round-trip them losslessly)
   * ``scopes``                the device stage names of the shard program
                               (``shuffle``, ``band/align``, ``band/cheap``,
-                              ``band/expensive``, ``band/select``): the
+                              ``band/expensive``, ``band/select``, and
+                              the shuffle's ``shuffle/route``,
+                              ``/exchange``, ``/sort``, ``/halo``): the
                               ``jax.named_scope``s a device profile carries
                               on every operation
 
-A device resolve records, under its ``attempt`` span: ``shard_program``
-(the device program, blocked on when traced), ``collect`` with its child
-``transfer`` (the one device-to-host fetch of the leaves collection reads;
-the ``transfer_bytes`` counter counts exactly those bytes), and
+A device resolve records, under its ``attempt`` span: on a mesh first
+``distribute`` (the mapper splits placed one per device; counter
+``distribute_bytes``), then ``shard_program`` (the device program,
+blocked on when traced), on a mesh ``exchange_counts`` (traced only:
+the ``shuffle.bytes``, ``shuffle.rows_moved`` and ``halo.rows`` counters,
+from the plan and the shapes on the host), ``collect`` with its
+child ``transfer`` (the one device-to-host fetch of the leaves collection
+reads; the ``transfer_bytes`` counter counts exactly those bytes), and
 ``to_outcome`` (the public frozensets built from the packed pairs).
 
 Every module here is a leaf (stdlib + numpy only at import time), so the

@@ -2,7 +2,10 @@
 
   * the compiled shard program's HLO metadata names every stage of
     ``repro.obs.scopes`` for srp / repsn / jobsn with both band engines,
-    under vmap here and under shard_map on 4 virtual devices
+    and every shuffle sub-stage the variant runs (SRP has no halo), under
+    vmap here and under shard_map on 4 virtual devices; the benchmark's
+    stage reader (``bench.scopes.stage_of``) charges each sub-stage to
+    ``shuffle``
   * the stages are metadata only: with them turned off the lowered program
     is the same text, the optimised program the same up to instruction
     names, and the executable-cache keys, trace counts and pair sets are
@@ -28,7 +31,7 @@ from repro import api
 from repro.api import results as RES
 from repro.core import entities as E
 from repro.core.match import CascadeMatcher, Matcher
-from repro.obs.scopes import STAGES
+from repro.obs.scopes import SHUFFLE_HALO, SHUFFLE_STAGES, STAGES
 from repro.perf import cache as PC
 
 REPO = Path(__file__).resolve().parents[1]
@@ -79,12 +82,37 @@ def programs(monkeypatch):
     cache.clear()
 
 
-def stages_named(hlo_text: str) -> set:
+def op_names(hlo_text: str) -> set:
+    """Every ``op_name`` path of an HLO text."""
+    return set(re.findall(r'op_name="([^"]*)"', hlo_text))
+
+
+def named_in(path: str, stage: str) -> bool:
+    """Whether an ``op_name`` path names ``stage`` as a whole element."""
+    return re.search(r"(^|[/(])" + re.escape(stage) + r"($|[/)])",
+                     path) is not None
+
+
+def stages_named(hlo_text: str, stages=STAGES) -> set:
     """The stages that some ``op_name`` of an HLO text names."""
-    paths = set(re.findall(r'op_name="([^"]*)"', hlo_text))
-    return {s for s in STAGES
-            if any(re.search(r"(^|[/(])" + re.escape(s) + r"($|[/)])", p)
-                   for p in paths)}
+    paths = op_names(hlo_text)
+    return {s for s in stages if any(named_in(p, s) for p in paths)}
+
+
+def shuffle_stages_of(variant: str) -> set:
+    """The shuffle sub-stages a variant runs: SRP has no halo."""
+    return set(SHUFFLE_STAGES) - ({SHUFFLE_HALO} if variant == "srp"
+                                  else set())
+
+
+def sub_stage_readings(hlo_text: str) -> set:
+    """What ``bench.scopes.stage_of`` makes of every path in a shuffle
+    sub-stage."""
+    if str(REPO) not in sys.path:
+        sys.path.insert(0, str(REPO))
+    from bench.scopes import stage_of
+    return {stage_of(p) for p in op_names(hlo_text)
+            if any(named_in(p, s) for s in SHUFFLE_STAGES)}
 
 
 def canonical(hlo_text: str) -> str:
@@ -114,7 +142,10 @@ def no_stages():
 def test_vmap_program_names_every_stage(ents, programs, variant, engine):
     api.resolve(ents, _cfg(variant=variant, band_engine=engine))
     (_, fn, args), = programs
-    assert stages_named(fn.lower(*args).compile().as_text()) == set(STAGES)
+    text = fn.lower(*args).compile().as_text()
+    assert stages_named(text) == set(STAGES)
+    assert stages_named(text, SHUFFLE_STAGES) == shuffle_stages_of(variant)
+    assert sub_stage_readings(text) == {"shuffle"}
 
 
 def test_shard_map_program_names_every_stage():
@@ -126,7 +157,9 @@ def test_shard_map_program_names_every_stage():
         from repro import api
         from repro.core import entities as E
         from repro.perf import cache as PC
-        from tests.test_device_scopes import MATCHER, stages_named
+        from repro.obs.scopes import SHUFFLE_STAGES
+        from tests.test_device_scopes import (MATCHER, stages_named,
+                                              sub_stage_readings)
         ents = E.synth_entities(np.random.default_rng(3), 300, n_keys=60,
                                 dup_frac=0.25, text_len=12)
         cache = PC.executable_cache()
@@ -149,7 +182,10 @@ def test_shard_map_program_names_every_stage():
                 api.resolve(ents, cfg)
                 fn, args = seen[0]
                 text = fn.lower(*args).compile().as_text()
-                out[variant + "/" + engine] = sorted(stages_named(text))
+                out[variant + "/" + engine] = [
+                    sorted(stages_named(text)),
+                    sorted(stages_named(text, SHUFFLE_STAGES)),
+                    sorted(sub_stage_readings(text))]
         print("@@" + json.dumps(out))
     """)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -159,7 +195,8 @@ def test_shard_map_program_names_every_stage():
     lines = [ln for ln in done.stdout.splitlines() if ln.startswith("@@")]
     assert lines, done.stderr[-3000:]
     got = json.loads(lines[-1][2:])
-    assert got == {f"{v}/{e}": sorted(STAGES)
+    assert got == {f"{v}/{e}": [sorted(STAGES),
+                                sorted(shuffle_stages_of(v)), ["shuffle"]]
                    for v in ("srp", "repsn", "jobsn")
                    for e in ("scan", "pallas")}
 
@@ -183,6 +220,8 @@ def test_stages_change_only_metadata(ents, programs, engine):
     assert r1.pairs == r2.pairs and r1.matches == r2.matches
     assert low1 == low2
     assert stages_named(hlo1) == set(STAGES) and not stages_named(hlo2)
+    assert stages_named(hlo1, SHUFFLE_STAGES) == set(SHUFFLE_STAGES)
+    assert not stages_named(hlo2, SHUFFLE_STAGES)
     assert canonical(hlo1) == canonical(hlo2)
 
 
