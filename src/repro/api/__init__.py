@@ -6,8 +6,10 @@ One entry point for the paper's parallel Sorted Neighborhood workflows:
 
     res = api.resolve(ents, api.ERConfig(variant="repsn", runner="vmap",
                                          num_shards=8, window=10))
-    res.blocking.pairs      # frozenset of blocked (candidate) pairs
-    res.matches             # frozenset of matcher-accepted pairs
+    res.blocking.pairs      # PairSet of blocked (candidate) pairs
+    res.matches             # PairSet of matcher-accepted pairs
+    # a PairSet equals and hashes like the frozenset of the same (lo, hi)
+    # tuples, but is not a frozenset instance
     res.blocking.load       # per-shard valid counts (skew telemetry)
     res.metrics             # reduction ratio / pairs completeness vs oracle
 
@@ -64,11 +66,12 @@ from repro.api.facade import (default_bounds, link, make_runner, resolve,
                               resume, serve)
 from repro.api.linkage import sequential_link_pairs, tag_sources
 from repro.api.results import (BalanceMetrics, BlockingResult, ERMetrics,
-                               ERResult, MultiPassResult, PerfStats,
-                               ResilienceStats, pack_pairs,
+                               ERResult, MultiPassResult, PairSet,
+                               PerfStats, ResilienceStats, pack_pairs,
                                packed_pairs_from_band, packed_pairs_from_idx,
                                packed_pairs_from_part, packed_to_frozenset,
-                               pairs_from_band, unpack_pairs)
+                               packed_to_pair_set, pairs_from_band,
+                               unpack_pairs)
 from repro.api.runners import (PackedOutcome, Runner, RunnerOutcome,
                                SequentialRunner, ShardMapRunner, VmapRunner,
                                shard_input)
@@ -107,7 +110,7 @@ __all__ = [
     "pairs_from_band",
     "packed_pairs_from_band", "packed_pairs_from_idx",
     "packed_pairs_from_part", "pack_pairs", "unpack_pairs",
-    "packed_to_frozenset",
+    "packed_to_frozenset", "packed_to_pair_set", "PairSet",
     "Runner", "RunnerOutcome", "PackedOutcome",
     "SequentialRunner", "VmapRunner", "ShardMapRunner", "shard_input",
     "register_variant", "get_variant", "available_variants",

@@ -7,16 +7,20 @@ quality metrics computed against the sequential oracle.
 Internally pairs travel as PACKED uint64 arrays — ``(lo << 32) | hi`` with
 ``lo < hi`` eids — deduplicated by ``np.unique``.  Collection is then one
 batched nonzero + pack + unique (linear, vectorized) instead of building
-millions of Python tuples; frozensets of (lo, hi) tuples appear only at the
-public ``RunnerOutcome``/``BlockingResult`` boundary.
+millions of Python tuples.  The public ``RunnerOutcome``/``BlockingResult``
+boundary wraps the arrays in ``PairSet``s, which equal and hash like the
+frozensets of (lo, hi) tuples and make a tuple only when iterated.
 """
 from __future__ import annotations
 
+import itertools
+from collections import abc
 from dataclasses import dataclass
-from typing import FrozenSet, NamedTuple, Optional, Set, Tuple
+from typing import AbstractSet, FrozenSet, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
+from repro import obs as OBS
 from repro.resilience.retry import ResilienceStats
 
 Pair = Tuple[int, int]
@@ -43,7 +47,10 @@ def unpack_pairs(packed: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def pack_pair_set(pairs: Set[Pair]) -> np.ndarray:
-    """Host pair set -> sorted deduplicated packed array."""
+    """Host pair set -> sorted deduplicated packed array (a ``PairSet``'s
+    own array, without materializing its pairs)."""
+    if isinstance(pairs, PairSet):
+        return pairs.packed
     if not pairs:
         return np.empty((0,), PACKED_DTYPE)
     flat = np.fromiter((c for p in pairs for c in p), np.int64,
@@ -51,11 +58,206 @@ def pack_pair_set(pairs: Set[Pair]) -> np.ndarray:
     return np.unique(pack_pairs(flat[:, 0], flat[:, 1]))
 
 
-def packed_to_frozenset(packed: np.ndarray) -> FrozenSet[Pair]:
-    """Packed array -> public frozenset of (lo, hi) tuples (the one place
-    Python pair objects are materialized)."""
-    lo, hi = unpack_pairs(packed)
-    return frozenset(zip(lo.tolist(), hi.tolist()))
+# CPython's (3.8+, 64-bit) hash constants: the xxHash-style tuple hash and
+# the frozenset hash of Objects/setobject.c
+_U64 = (1 << 64) - 1
+_XXPRIME_1 = np.uint64(11400714785074694791)
+_XXPRIME_2 = np.uint64(14029467366897019727)
+_XXPRIME_5 = np.uint64(2870177450012600261)
+_TUPLE2_LEN = np.uint64(2 ^ (2870177450012600261 ^ 3527539))
+_CHUNK = 1 << 16        # pairs per step of the hash and of iteration
+_MATERIALIZED = "pairs.materialized"
+
+
+def _rotl31(acc: np.ndarray, tmp: np.ndarray) -> None:
+    np.left_shift(acc, np.uint64(31), out=tmp)
+    np.right_shift(acc, np.uint64(33), out=acc)
+    np.bitwise_or(acc, tmp, out=acc)
+
+
+def _tuple_hashes(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """``hash((lo, hi))`` elementwise, as uint64 bits, for eids whose int
+    hash is the eid itself (0 <= eid < 2**61 - 1)."""
+    acc = np.multiply(lo, _XXPRIME_2, dtype=np.uint64)
+    tmp = np.empty_like(acc)
+    np.add(acc, _XXPRIME_5, out=acc)
+    _rotl31(acc, tmp)
+    np.multiply(acc, _XXPRIME_1, out=acc)
+    np.multiply(hi, _XXPRIME_2, out=tmp)
+    np.add(acc, tmp, out=acc)
+    _rotl31(acc, tmp)
+    np.multiply(acc, _XXPRIME_1, out=acc)
+    np.add(acc, _TUPLE2_LEN, out=acc)
+    acc[acc == np.uint64(_U64)] = 1546275796    # -1 is CPython's error code
+    return acc
+
+
+def _shuffled_xor(h: np.ndarray) -> int:
+    """XOR over ``_shuffle_bits`` of each element hash in ``h`` (clobbered)."""
+    tmp = np.left_shift(h, np.uint64(16))
+    np.bitwise_xor(h, np.uint64(89869747), out=h)
+    np.bitwise_xor(h, tmp, out=h)
+    np.multiply(h, np.uint64(3644798167), out=h)
+    return int(np.bitwise_xor.reduce(h)) if h.size else 0
+
+
+def _frozenset_hash(shuffled_xor: int, size: int) -> int:
+    """CPython's frozenset hash from the XOR of its entries' shuffled
+    hashes and its size (the size term and the final dispersion)."""
+    h = shuffled_xor ^ (((size + 1) * 1927868237) & _U64)
+    h ^= (h >> 11) ^ (h >> 25)
+    h = (h * 69069 + 907133923) & _U64
+    if h == _U64:
+        h = 590923713
+    return h - (1 << 64) if h >> 63 else h
+
+
+def _union(a: np.ndarray, b: np.ndarray, assume_unique: bool) -> np.ndarray:
+    return np.union1d(a, b)
+
+
+def _materialized(n: int) -> None:
+    """Count ``n`` pairs turned into Python objects, under a tracer."""
+    tracer = OBS.current_tracer()
+    if tracer is not None:
+        tracer.metrics.counter(_MATERIALIZED).inc(n)
+
+
+class PairSet(abc.Set):
+    """Immutable set of (lo, hi) eid pairs over a sorted, deduplicated
+    packed uint64 array (``pack_pairs``): the public pair set of every
+    result.  It equals, and hashes like, the frozenset of the same tuples,
+    so either serves as the other's dict key; ``isinstance(s, frozenset)``
+    is False.
+
+    ``len``, ``in``, ``hash``, ``==`` with another ``PairSet`` and the
+    operators ``& | - ^`` between two ``PairSet``s work on the array and
+    return ``PairSet``s; nothing builds a Python tuple.  Iteration yields
+    (lo, hi) tuples of Python ints in sorted order.  Algebra with any other
+    set or iterable materializes this set as a frozenset and returns a
+    frozenset (a ``set`` on the left of a reflected operator keeps its
+    type).  Under a tracer, each iteration or such fallback adds ``len``
+    to the ``pairs.materialized`` counter.
+
+    The array is held as a read-only view, without a copy: whoever hands
+    it over must not write to it afterwards."""
+
+    __slots__ = ("_packed", "_hash")
+
+    def __init__(self, packed: np.ndarray):
+        a = np.asarray(packed, PACKED_DTYPE).view()
+        a.flags.writeable = False
+        self._packed = a
+        self._hash = None
+
+    @property
+    def packed(self) -> np.ndarray:
+        """The sorted deduplicated packed uint64 array (read-only)."""
+        return self._packed
+
+    def __len__(self) -> int:
+        return self._packed.size
+
+    def __contains__(self, pair) -> bool:
+        hash(pair)          # an unhashable probe raises, as for a frozenset
+        if not isinstance(pair, tuple) or len(pair) != 2:
+            return False
+        lo, hi = pair
+        # a frozenset finds the tuple iff each element hashes like and
+        # equals the stored eid, whose hash is the eid itself
+        a, b = hash(lo), hash(hi)
+        if not (0 <= a <= 0xFFFFFFFF and 0 <= b <= 0xFFFFFFFF) \
+                or lo != a or hi != b:
+            return False
+        key = np.uint64((a << 32) | b)
+        i = int(np.searchsorted(self._packed, key))
+        return i < self._packed.size and bool(self._packed[i] == key)
+
+    def _chunk(self, i: int):
+        p = self._packed[i:i + _CHUNK]
+        return zip((p >> np.uint64(32)).tolist(),
+                   (p & np.uint64(0xFFFFFFFF)).tolist())
+
+    def __iter__(self):
+        _materialized(len(self))
+        return itertools.chain.from_iterable(
+            map(self._chunk, range(0, self._packed.size, _CHUNK)))
+
+    def __hash__(self) -> int:
+        if self._hash is None:
+            x = 0
+            for i in range(0, self._packed.size, _CHUNK):
+                p = self._packed[i:i + _CHUNK]
+                x ^= _shuffled_xor(_tuple_hashes(
+                    p >> np.uint64(32), p & np.uint64(0xFFFFFFFF)))
+            self._hash = _frozenset_hash(x, self._packed.size)
+        return self._hash
+
+    def __eq__(self, other):
+        if isinstance(other, PairSet):
+            return np.array_equal(self._packed, other._packed)
+        return super().__eq__(other)
+
+    def __reduce__(self):
+        return PairSet, (self._packed,)
+
+    def __repr__(self) -> str:
+        return f"PairSet(<{len(self)} pairs>)"
+
+    @classmethod
+    def _from_iterable(cls, it):
+        return frozenset(it)
+
+    def _algebra(self, other, vectorised, name: str):
+        if isinstance(other, PairSet):
+            return PairSet(vectorised(self._packed, other._packed,
+                                      assume_unique=True))
+        if not isinstance(other, abc.Iterable):
+            return NotImplemented
+        if not isinstance(other, (set, frozenset)):
+            other = frozenset(other)
+        return getattr(frozenset(self), name)(other)
+
+    def _reflected(self, other, name: str):
+        if not isinstance(other, abc.Iterable):
+            return NotImplemented
+        if not isinstance(other, (set, frozenset)):
+            other = frozenset(other)
+        return getattr(other, name)(frozenset(self))
+
+    def __and__(self, other):
+        return self._algebra(other, np.intersect1d, "__and__")
+
+    def __or__(self, other):
+        return self._algebra(other, _union, "__or__")
+
+    def __sub__(self, other):
+        return self._algebra(other, np.setdiff1d, "__sub__")
+
+    def __xor__(self, other):
+        return self._algebra(other, np.setxor1d, "__xor__")
+
+    def __rand__(self, other):
+        return self._reflected(other, "__and__")
+
+    def __ror__(self, other):
+        return self._reflected(other, "__or__")
+
+    def __rsub__(self, other):
+        return self._reflected(other, "__sub__")
+
+    def __rxor__(self, other):
+        return self._reflected(other, "__xor__")
+
+
+def packed_to_pair_set(packed: np.ndarray) -> PairSet:
+    """Sorted deduplicated packed array -> public ``PairSet``, with no copy
+    and no Python pair object made."""
+    return PairSet(packed)
+
+
+# the historical name: the public sets were frozensets of tuples
+packed_to_frozenset = packed_to_pair_set
 
 
 class CollectedPairs(NamedTuple):
@@ -149,7 +351,9 @@ class ERMetrics:
 @dataclass(frozen=True)
 class BlockingResult:
     """Outcome of the blocking stage (candidate generation)."""
-    pairs: FrozenSet[Pair]          # blocked (candidate) pairs, (lo, hi) eids
+    pairs: AbstractSet[Pair]        # blocked (candidate) pairs, (lo, hi)
+    #                                 eids: a PairSet; a frozenset for the
+    #                                 multi-pass union and linkage
     load: Tuple[int, ...]           # per-shard valid counts (skew telemetry)
     overflow: int                   # entities dropped by capacity limits
     variant: str
@@ -190,7 +394,8 @@ class ERResult:
     backed ShardPlan (any ``cfg.partitioner`` default-bounds run); runs on
     explicit raw bounds have no plan to compare against and carry None."""
     blocking: BlockingResult
-    matches: FrozenSet[Pair]        # matcher-accepted pairs
+    matches: AbstractSet[Pair]      # matcher-accepted pairs: a PairSet; a
+    #                                 frozenset after linkage untagging
     metrics: Optional[ERMetrics] = None
     balance: Optional[BalanceMetrics] = None
     perf: Optional[PerfStats] = None  # executable-cache telemetry for this
@@ -204,7 +409,7 @@ class ERResult:
     #                                 unified — DESIGN.md §12)
 
     @property
-    def pairs(self) -> FrozenSet[Pair]:
+    def pairs(self) -> AbstractSet[Pair]:
         """The blocked (candidate) pair set — sugar for blocking.pairs."""
         return self.blocking.pairs
 
@@ -343,7 +548,7 @@ def pairs_from_band(part: dict, field: str = "match") -> Set[Pair]:
     return set(zip(lo.tolist(), hi.tolist()))
 
 
-def compute_metrics(blocked: FrozenSet[Pair], oracle: Set[Pair],
+def compute_metrics(blocked: AbstractSet[Pair], oracle: Set[Pair],
                     total_comparisons: int) -> ERMetrics:
     """Standard blocking-quality metrics of ``blocked`` against the
     sequential-SN ``oracle`` pair set: reduction ratio = 1 − |blocked| /

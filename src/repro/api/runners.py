@@ -34,8 +34,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, FrozenSet, NamedTuple, Protocol, Tuple, \
-    runtime_checkable
+from typing import Any, NamedTuple, Protocol, Tuple, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +47,6 @@ from repro.api.variants import get_variant, link_capacity
 from repro.balance.planners import as_plan
 from repro.core import entities as E
 from repro.perf import cache as PC
-
-Pair = Tuple[int, int]
 
 
 def _apply_plan(ents: dict, bounds, r: int, cfg):
@@ -97,8 +94,8 @@ class RunnerOutcome(NamedTuple):
     one-matcher cascade, one per cand_cap buffer slot for pallas (static
     shapes, reported honestly so benchmarks can verify the §5.1 FLOP
     cut)."""
-    blocked: FrozenSet[Pair]
-    matched: FrozenSet[Pair]
+    blocked: RES.PairSet
+    matched: RES.PairSet
     load: Tuple[int, ...]
     overflow: int
     num_shards: int
@@ -121,8 +118,8 @@ class PackedOutcome(NamedTuple):
     This is the collection hot path for callers that aggregate MANY runner
     invocations — ``repro.stream`` unions one of these per chunk with a
     single ``np.unique`` at the end, instead of materializing a Python
-    frozenset per chunk.  ``to_outcome()`` converts to the public tuple-set
-    form (the one place Python pair objects appear)."""
+    pair set per chunk.  ``to_outcome()`` wraps the arrays in the public
+    ``PairSet`` form."""
     blocked: "np.ndarray"
     matched: "np.ndarray"
     load: Tuple[int, ...]
@@ -135,12 +132,16 @@ class PackedOutcome(NamedTuple):
     pruned: int = 0
 
     def to_outcome(self) -> RunnerOutcome:
-        """Materialize the public RunnerOutcome (frozensets of (lo, hi)),
-        inside a ``to_outcome`` span."""
-        with OBS.span("to_outcome"):
+        """The public RunnerOutcome (``results.PairSet``s over the packed
+        arrays, no copy), inside a ``to_outcome`` span.  Under a tracer the
+        ``pairs.materialized`` counter is registered here, so a job whose
+        pair sets are never iterated reads 0."""
+        with OBS.span("to_outcome") as sp:
+            if sp.enabled:
+                OBS.current_tracer().metrics.counter("pairs.materialized")
             return RunnerOutcome(
-                blocked=RES.packed_to_frozenset(self.blocked),
-                matched=RES.packed_to_frozenset(self.matched),
+                blocked=RES.packed_to_pair_set(self.blocked),
+                matched=RES.packed_to_pair_set(self.matched),
                 load=self.load, overflow=self.overflow,
                 num_shards=self.num_shards, cand_count=self.cand_count,
                 cand_overflow=self.cand_overflow,
@@ -161,7 +162,7 @@ class Runner(Protocol):
         ...
 
     def resolve(self, ents: dict, bounds, cfg) -> RunnerOutcome:
-        """Run blocking + matching; pair sets as frozensets of (lo, hi)."""
+        """Run blocking + matching; pair sets as ``results.PairSet``s."""
         ...
 
     def resolve_packed(self, ents: dict, bounds, cfg) -> PackedOutcome:

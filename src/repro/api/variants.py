@@ -169,8 +169,8 @@ class VariantBase:
     def collect(self, out: dict) -> RES.CollectedPairs:
         """Stacked runner output -> deduplicated PACKED pair arrays (uint64
         ``(lo << 32) | hi``).  Parts are unioned via np.unique, so a pair
-        emitted by several parts/shards counts once; frozensets appear only
-        at the RunnerOutcome boundary.  Device-emitted parts (emit="pairs")
+        emitted by several parts/shards counts once; the RunnerOutcome
+        boundary wraps the arrays in ``results.PairSet``s.  Device-emitted parts (emit="pairs")
         and band parts are consumed transparently
         (``results.packed_pairs_from_part``)."""
         blocked = [RES.packed_pairs_from_part(out[p], "mask")

@@ -35,7 +35,9 @@ the ``shuffle.bytes``, ``shuffle.rows_moved`` and ``halo.rows`` counters,
 from the plan and the shapes on the host), ``collect`` with its
 child ``transfer`` (the one device-to-host fetch of the leaves collection
 reads; the ``transfer_bytes`` counter counts exactly those bytes), and
-``to_outcome`` (the public frozensets built from the packed pairs).
+``to_outcome`` (the public ``PairSet``s wrapped around the packed pairs;
+counter ``pairs.materialized``, registered there, adds the pairs later
+turned into tuples).
 
 Every module here is a leaf (stdlib + numpy only at import time), so the
 instrumented subsystems — ``repro.api``, ``repro.stream``, ``repro.serve``,

@@ -45,8 +45,9 @@ def _as_packed(pairs) -> np.ndarray:
     """Anything pair-shaped -> deduplicated packed uint64 array.
 
     Accepts a resolve result (ERResult / MultiPassResult / StreamResult —
-    anything with ``.pairs`` or ``.blocking.pairs``), a set/frozenset of
-    (lo, hi) tuples, or an already-packed uint64 array."""
+    anything with ``.pairs`` or ``.blocking.pairs``), a ``PairSet`` (its
+    array, read without a tuple), a set/frozenset of (lo, hi) tuples, or an
+    already-packed uint64 array."""
     from repro.api import results as RES
 
     if hasattr(pairs, "blocking"):

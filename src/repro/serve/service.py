@@ -42,7 +42,7 @@ import queue
 import threading
 import time
 from concurrent.futures import Future, InvalidStateError
-from typing import Dict, FrozenSet, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -127,10 +127,10 @@ class IncrementalResult(NamedTuple):
     ``degraded=True`` marks a batch applied through the brownout path:
     its blocked edits are exact, but new matches are deferred until the
     ``repair`` pass re-resolves the touched ranges (DESIGN.md §13)."""
-    new_pairs: FrozenSet[Pair]
-    retired_pairs: FrozenSet[Pair]
-    new_matches: FrozenSet[Pair]
-    retired_matches: FrozenSet[Pair]
+    new_pairs: RES.PairSet
+    retired_pairs: RES.PairSet
+    new_matches: RES.PairSet
+    retired_matches: RES.PairSet
     pair_ids: Dict[Pair, int]
     batched: int
     stats: ServeStats
@@ -613,10 +613,10 @@ class ResolutionService:
                 self._latency.observe(now - r.t0)
             stats = self._stats_locked()
         return IncrementalResult(
-            new_pairs=RES.packed_to_frozenset(new_p),
-            retired_pairs=RES.packed_to_frozenset(gone_p),
-            new_matches=RES.packed_to_frozenset(new_m),
-            retired_matches=RES.packed_to_frozenset(gone_m),
+            new_pairs=RES.packed_to_pair_set(new_p),
+            retired_pairs=RES.packed_to_pair_set(gone_p),
+            new_matches=RES.packed_to_pair_set(new_m),
+            retired_matches=RES.packed_to_pair_set(gone_m),
             pair_ids=ids, batched=len(group), stats=stats,
             degraded=dstats.degraded)
 
@@ -696,14 +696,14 @@ class ResolutionService:
             return self._served_m
 
     @property
-    def pairs(self) -> FrozenSet[Pair]:
+    def pairs(self) -> RES.PairSet:
         """Currently served blocked set as (lo, hi) eid tuples."""
-        return RES.packed_to_frozenset(self.packed_pairs)
+        return RES.packed_to_pair_set(self.packed_pairs)
 
     @property
-    def matches(self) -> FrozenSet[Pair]:
+    def matches(self) -> RES.PairSet:
         """Currently served matched set as (lo, hi) eid tuples."""
-        return RES.packed_to_frozenset(self.packed_matches)
+        return RES.packed_to_pair_set(self.packed_matches)
 
     def pair_id(self, pair: Pair) -> int:
         """Stable id of a pair the service has served at any point."""

@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, replace
-from typing import FrozenSet, Iterable, Optional, Set, Tuple
+from typing import AbstractSet, Iterable, Optional, Set, Tuple
 
 import numpy as np
 
@@ -122,7 +122,8 @@ class StreamResult:
     overflow counters aggregate additively.  ``stream`` carries the
     streaming telemetry; per-pass results keep their own."""
     blocking: BlockingResult
-    matches: FrozenSet[Pair]
+    matches: AbstractSet[Pair]     # a PairSet; a frozenset for the
+    #                                multi-pass union
     stream: StreamStats
     metrics: Optional[ERMetrics] = None
     passes: Tuple["StreamResult", ...] = ()
@@ -137,7 +138,7 @@ class StreamResult:
     trace: Optional[object] = None
 
     @property
-    def pairs(self) -> FrozenSet[Pair]:
+    def pairs(self) -> AbstractSet[Pair]:
         """The blocked (candidate) pair set — sugar for blocking.pairs."""
         return self.blocking.pairs
 
@@ -485,7 +486,7 @@ def _stream_pass_body(raw: ChunkStore, cfg: ERConfig, spec,
     blocked = dedup(blocked_parts)
     matched = dedup(matched_parts)
     blocking = BlockingResult(
-        pairs=RES.packed_to_frozenset(blocked),
+        pairs=RES.packed_to_pair_set(blocked),
         load=tuple(int(x) for x in load_max), overflow=overflow,
         variant=cfg.variant, runner=runner.name, window=w, num_shards=r,
         cand_count=tuple(int(x) for x in cand_max),
@@ -511,7 +512,7 @@ def _stream_pass_body(raw: ChunkStore, cfg: ERConfig, spec,
     if ckpt is not None:
         ckpt.mark_pass_done(label)
     return StreamResult(
-        blocking=blocking, matches=RES.packed_to_frozenset(matched),
+        blocking=blocking, matches=RES.packed_to_pair_set(matched),
         stream=stats, metrics=metrics, resilience=resilience), oracle
 
 

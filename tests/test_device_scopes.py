@@ -12,7 +12,8 @@
     unchanged; traced and untraced runs share keys and pair sets
   * a traced resolve records ``to_outcome`` under ``attempt`` and
     ``transfer`` under ``collect``; ``transfer_bytes`` counts exactly the
-    leaves fetched, which hold no payload
+    leaves fetched, which hold no payload; its public pair sets are
+    ``PairSet``s that count ``pairs.materialized`` only when iterated
 """
 import contextlib
 import json
@@ -245,6 +246,25 @@ def test_traced_resolve_records_to_outcome_and_transfer(ents):
     assert parents["collect"] == "attempt"
     collect = next(s for s in spans if s.name == "collect")
     assert "transfer_bytes" not in collect.attrs and "load" in collect.attrs
+
+
+def test_public_pair_sets_materialize_only_when_iterated(ents):
+    from repro import obs
+    tracer = obs.Tracer()
+    count = lambda: tracer.metrics.to_dict()["pairs.materialized"]["value"]
+    with obs.activate(tracer):
+        res = api.resolve(ents, _cfg(variant="repsn"))
+        for s in (res.blocking.pairs, res.matches):
+            assert isinstance(s, RES.PairSet) and hash(s) == hash(frozenset(
+                zip(*(x.tolist() for x in RES.unpack_pairs(s.packed)))))
+            len(s)
+        assert count() == 0
+        n = len(res.blocking.pairs)
+        assert n > 0 and len(list(res.blocking.pairs)) == n
+        assert count() == n
+    name = {s.index: s.name for s in tracer.spans()}
+    assert [name.get(s.parent) for s in tracer.spans()
+            if s.name == "to_outcome"] == ["attempt"]
 
 
 @pytest.mark.parametrize("emit", ["band", "pairs"])
