@@ -39,7 +39,6 @@ Tables:
                         parity; writes BENCH_recall.json
   kernels               Pallas band kernels vs jnp oracle (CPU timings)
   dedup_e2e             end-to-end corpus dedup throughput + SN-vs-n^2 factor
-  roofline              summary of dry-run roofline terms (needs artifacts)
 
 Every BENCH_*.json goes through ``write_bench``, which stamps the shared
 ``schema_version`` (``repro.obs.SCHEMA_VERSION``) and a ``machine_proxy_s``
@@ -365,12 +364,6 @@ def kernels(quick: bool):
     us_j = timeit(ref.jaccard_band_ref, sig, window=w)
     _row("kernel_jaccard_ref_jnp", us_j, f"pairs_per_s={m*w/us_j*1e6:.2e}")
 
-    bh, s, d, win = (4, 1024, 64, 256) if quick else (8, 4096, 128, 1024)
-    q = jnp.asarray(rng.normal(size=(bh, s, d)).astype(np.float32))
-    us_a = timeit(ref.local_attention_ref, q, q, q, window=win, reps=3)
-    _row("kernel_local_attn_ref_jnp", us_a,
-         f"gflops={4*bh*s*win*d/us_a/1e3:.2f}")
-
 
 def dedup_e2e(quick: bool):
     from repro.data.corpus import dedup_corpus, synth_corpus
@@ -384,23 +377,6 @@ def dedup_e2e(quick: bool):
     _row("dedup_e2e", dt * 1e6,
          f"docs_per_s={n/dt:.0f};dropped={res.n_dropped};"
          f"cmp_reduction={naive_cmp/sn_cmp:.0f}x;gini={res.gini:.2f}")
-
-
-def roofline(quick: bool):
-    from benchmarks.roofline import load_all
-    rows = load_all()
-    if not rows:
-        _row("roofline", 0.0, "no-dryrun-artifacts")
-        return
-    worst = min(rows, key=lambda r: r["roofline_fraction"])
-    for r in rows:
-        _row(f"roofline_{r['arch']}_{r['shape']}_{r['mesh']}",
-             max(r["t_compute_s"], r["t_memory_s"],
-                 r["t_collective_s"]) * 1e6,
-             f"dominant={r['dominant']};frac={r['roofline_fraction']:.2f};"
-             f"useful={r['useful_ratio']:.2f}")
-    _row("roofline_worst_cell", 0.0,
-         f"{worst['arch']}/{worst['shape']}:{worst['roofline_fraction']:.2f}")
 
 
 TABLES = {
@@ -417,7 +393,6 @@ TABLES = {
     "recall": recall,
     "kernels": kernels,
     "dedup_e2e": dedup_e2e,
-    "roofline": roofline,
 }
 
 

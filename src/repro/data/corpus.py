@@ -1,16 +1,13 @@
 """Data pipeline: deterministic synthetic corpus + SN-dedup integration.
 
-The paper's technique is a *data-pipeline stage*: corpus deduplication before
-LM training.  ``DedupPipeline`` runs documents through the distributed SN
-blocking + matching workflow and yields a keep-mask; ``TokenBatcher`` then
-serves deterministic, step-indexed token batches (resumable: batch(step) is a
-pure function of (seed, step), so crash recovery replays exactly — see
-train/loop.py fault handling).
+The paper's technique as a *data-pipeline stage*: corpus deduplication.
+``dedup_corpus`` runs documents through the distributed SN blocking +
+matching workflow and yields a keep-mask.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterator, Optional, Set, Tuple
+from typing import Iterator, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -195,32 +192,3 @@ def dedup_corpus(docs: np.ndarray, *, r: int = 4, window: int = 10,
     return DedupResult(keep=keep, n_pairs=len(res.matches),
                        n_dropped=int((~keep).sum()),
                        gini=P.gini(sizes), overflow=res.blocking.overflow)
-
-
-# -- deterministic token batcher ----------------------------------------------------
-
-@dataclass
-class TokenBatcher:
-    """batch(step) is a pure function of (seed, step): crash recovery replays
-    the exact data order (fault tolerance requires deterministic data)."""
-    docs: np.ndarray                  # (n_docs, L) post-dedup
-    seq_len: int
-    global_batch: int
-    seed: int = 0
-
-    def __post_init__(self):
-        flat = self.docs.reshape(-1)
-        n_tok = (flat.shape[0] // self.seq_len) * self.seq_len
-        self.stream = flat[:n_tok].reshape(-1, self.seq_len)
-
-    @property
-    def n_sequences(self) -> int:
-        return self.stream.shape[0]
-
-    def batch(self, step: int) -> Dict[str, np.ndarray]:
-        rng = np.random.default_rng((self.seed << 20) ^ step)
-        idx = rng.integers(0, self.n_sequences, size=self.global_batch)
-        toks = self.stream[idx].astype(np.int32)
-        labels = np.concatenate(
-            [toks[:, 1:], np.full((toks.shape[0], 1), -1, np.int32)], axis=1)
-        return {"tokens": toks, "labels": labels}

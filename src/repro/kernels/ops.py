@@ -9,12 +9,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels.banded_sim import banded_sim_tiles
 from repro.kernels.fused_band import NATIVE_BLOCK_ALIGN, fused_band_scores
 from repro.kernels.jaccard_band import jaccard_band_tiles
-from repro.kernels.local_attn import local_attention
 
 
 def default_interpret() -> bool:
@@ -112,17 +110,3 @@ def fused_cheap_band(feat: jax.Array, sig: jax.Array, *, window: int,
     return fused_band_scores(feat, sig, window=window, w_cos=w_cos,
                              w_jac=w_jac, block_i=bi, m_valid=m,
                              interpret=interpret)[:m]
-
-
-@partial(jax.jit,
-         static_argnames=("window", "block_q", "block_k", "softcap",
-                          "interpret"))
-def local_attn(q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
-               block_q: int = 256, block_k: int = 256, softcap: float = 0.0,
-               interpret: bool = None) -> jax.Array:
-    """Sliding-window flash attention: (BH, S, D) x3 -> (BH, S, D)."""
-    interpret = default_interpret() if interpret is None else interpret
-    s = q.shape[1]
-    bq = bk = min(block_q, block_k, s)
-    return local_attention(q, k, v, window=window, block_q=bq, block_k=bk,
-                           softcap=softcap, interpret=interpret)

@@ -1,11 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (allclose targets in tests)."""
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def banded_sim_ref(feat: jax.Array, *, window: int) -> jax.Array:
@@ -33,19 +30,3 @@ def jaccard_band_ref(sig: jax.Array, *, window: int) -> jax.Array:
         cols.append(jnp.where(ok, jac, 0.0))
     return jnp.stack(cols, axis=1)
 
-
-def local_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        window: int, softcap: float = 0.0) -> jax.Array:
-    """(BH, S, D) causal sliding-window attention, materialized scores."""
-    bh, s, d = q.shape
-    sc = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                    k.astype(jnp.float32)) / math.sqrt(d)
-    if softcap:
-        sc = softcap * jnp.tanh(sc / softcap)
-    qp = jnp.arange(s)[:, None]
-    kp = jnp.arange(s)[None, :]
-    mask = (kp <= qp) & (kp > qp - window)
-    sc = jnp.where(mask, sc, -1e30)
-    p = jax.nn.softmax(sc, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)

@@ -30,9 +30,6 @@ config, for all three variants and both band engines.  Under brownout
 the invariant relaxes to EVENTUALLY-exact (DESIGN.md §13): blocked pairs
 stay exact throughout, new matches may be deferred, and ``repair()``
 restores full bit-parity once pressure drops.
-
-(This package previously quarantined the seed repo's LM-serving
-scaffolding; that scaffold is gone — the SN serving layer lives here.)
 """
 from repro.serve.admission import (AdmissionConfig, AdmissionError,
                                    BatchTimeoutError, DeadlineExceededError,

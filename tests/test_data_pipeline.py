@@ -1,8 +1,7 @@
-"""Data pipeline: dedup stage behaviour + deterministic batching."""
+"""Data pipeline: dedup stage behaviour."""
 import numpy as np
 
-from repro.data.corpus import (TokenBatcher, dedup_corpus, doc_entities,
-                               synth_corpus)
+from repro.data.corpus import dedup_corpus, doc_entities, synth_corpus
 
 
 def test_dedup_finds_planted_duplicates():
@@ -23,19 +22,6 @@ def test_dedup_never_drops_all():
     docs = synth_corpus(1, 256, doc_len=16, vocab=100, dup_frac=0.9)
     res = dedup_corpus(docs, r=2, window=6)
     assert res.keep.sum() >= len(np.unique(docs, axis=0)) * 0.5
-
-
-def test_batcher_deterministic_and_resumable():
-    docs = synth_corpus(2, 128, doc_len=64, vocab=1000)
-    b1 = TokenBatcher(docs, seq_len=64, global_batch=4, seed=3)
-    b2 = TokenBatcher(docs, seq_len=64, global_batch=4, seed=3)
-    for step in [0, 5, 17]:
-        np.testing.assert_array_equal(b1.batch(step)["tokens"],
-                                      b2.batch(step)["tokens"])
-    # labels are next-token shifted with -1 tail mask
-    b = b1.batch(0)
-    np.testing.assert_array_equal(b["labels"][:, :-1], b["tokens"][:, 1:])
-    assert (b["labels"][:, -1] == -1).all()
 
 
 def test_doc_entities_shapes():

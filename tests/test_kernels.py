@@ -44,38 +44,6 @@ def test_jaccard_band(m, words, w, bi):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("bh,s,d,w,blk", [
-    (4, 512, 64, 128, 128),
-    (2, 1024, 128, 256, 256),
-    (2, 512, 64, 100, 128),   # window not a multiple of block
-    (1, 256, 128, 256, 128),  # window == seq (== dense causal)
-    (3, 768, 64, 384, 128),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_local_attention(bh, s, d, w, blk, dtype):
-    q = jnp.asarray(RNG.normal(size=(bh, s, d)).astype(np.float32), dtype)
-    k = jnp.asarray(RNG.normal(size=(bh, s, d)).astype(np.float32), dtype)
-    v = jnp.asarray(RNG.normal(size=(bh, s, d)).astype(np.float32), dtype)
-    got = ops.local_attn(q, k, v, window=w, block_q=blk, block_k=blk,
-                         interpret=True)
-    want = ref.local_attention_ref(q, k, v, window=w)
-    tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=tol, atol=tol)
-
-
-def test_local_attention_softcap():
-    q = jnp.asarray(RNG.normal(size=(2, 256, 64)).astype(np.float32))
-    k = jnp.asarray(RNG.normal(size=(2, 256, 64)).astype(np.float32))
-    v = jnp.asarray(RNG.normal(size=(2, 256, 64)).astype(np.float32))
-    got = ops.local_attn(q, k, v, window=128, block_q=128, block_k=128,
-                         softcap=20.0, interpret=True)
-    want = ref.local_attention_ref(q, k, v, window=128, softcap=20.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
-
-
 @pytest.mark.parametrize("m,f,words,w,bi", [
     (256, 64, 8, 16, 256),
     (300, 32, 4, 10, 128),   # non-multiple M (padding path)
