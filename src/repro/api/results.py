@@ -5,9 +5,11 @@ pair sets, per-shard load, overflow accounting, and (optionally) blocking
 quality metrics computed against the sequential oracle.
 
 Internally pairs travel as PACKED uint64 arrays — ``(lo << 32) | hi`` with
-``lo < hi`` eids — deduplicated by ``np.unique``.  Collection is then one
-batched nonzero + pack + unique (linear, vectorized) instead of building
-millions of Python tuples.  The public ``RunnerOutcome``/``BlockingResult``
+``lo < hi`` eids — sorted and deduplicated.  Collection of a band part
+packs every band slot once from the eid vector (the slot map), takes each
+pair set by one boolean compress of that map, and sorts it in place
+(linear passes plus one sort) instead of building millions of Python
+tuples.  The public ``RunnerOutcome``/``BlockingResult``
 boundary wraps the arrays in ``PairSet``s, which equal and hash like the
 frozensets of (lo, hi) tuples and make a tuple only when iterated.
 """
@@ -67,6 +69,8 @@ _XXPRIME_5 = np.uint64(2870177450012600261)
 _TUPLE2_LEN = np.uint64(2 ^ (2870177450012600261 ^ 3527539))
 _CHUNK = 1 << 16        # pairs per step of the hash and of iteration
 _MATERIALIZED = "pairs.materialized"
+_SLOTS = "collect.slots"
+_DUPLICATES = "collect.duplicates"
 
 
 def _rotl31(acc: np.ndarray, tmp: np.ndarray) -> None:
@@ -116,11 +120,11 @@ def _union(a: np.ndarray, b: np.ndarray, assume_unique: bool) -> np.ndarray:
     return np.union1d(a, b)
 
 
-def _materialized(n: int) -> None:
-    """Count ``n`` pairs turned into Python objects, under a tracer."""
+def _count(name: str, n: int) -> None:
+    """Add ``n`` to the counter ``name``, under a tracer."""
     tracer = OBS.current_tracer()
     if tracer is not None:
-        tracer.metrics.counter(_MATERIALIZED).inc(n)
+        tracer.metrics.counter(name).inc(n)
 
 
 class PairSet(abc.Set):
@@ -179,7 +183,7 @@ class PairSet(abc.Set):
                    (p & np.uint64(0xFFFFFFFF)).tolist())
 
     def __iter__(self):
-        _materialized(len(self))
+        _count(_MATERIALIZED, len(self))
         return itertools.chain.from_iterable(
             map(self._chunk, range(0, self._packed.size, _CHUNK)))
 
@@ -509,9 +513,17 @@ def packed_pairs_from_idx(part: dict, field: str = "match") -> np.ndarray:
 def packed_pairs_from_part(part: dict, field: str = "match") -> np.ndarray:
     """Collect a part through whichever representation it carries:
     device-emitted index buffers (emit="pairs") or boolean bands."""
-    if field + "_idx" in part:
-        return packed_pairs_from_idx(part, field)
-    return packed_pairs_from_band(part, field)
+    return packed_pair_sets_from_part(part, (field,))[0]
+
+
+def packed_pair_sets_from_part(part: dict, fields=("mask", "match")
+                               ) -> Tuple[np.ndarray, ...]:
+    """One sorted deduplicated packed array per field of ``fields``, from
+    the part's index buffers (emit="pairs") or, through one slot map, from
+    its bands (``packed_pair_sets_from_band``)."""
+    if all(f + "_idx" in part for f in fields):
+        return tuple(packed_pairs_from_idx(part, f) for f in fields)
+    return packed_pair_sets_from_band(part, fields)
 
 
 def packed_pairs_from_band(part: dict, field: str = "match") -> np.ndarray:
@@ -520,16 +532,77 @@ def packed_pairs_from_band(part: dict, field: str = "match") -> np.ndarray:
     ``part``: stacked per-shard output dict with ``eid`` (r, M), at its top
     level or under ``ents``, and a boolean band ``field`` of shape
     (r, w-1, M); band[s, d-1, i] pairs slot i with slot i+d of shard s.
-    One batched nonzero + pack + ``np.unique`` — no Python pair objects
-    anywhere on the path."""
+    One slot map, one boolean compress, one in-place sort — no Python pair
+    objects anywhere on the path (``packed_pair_sets_from_band``)."""
+    return packed_pair_sets_from_band(part, (field,))[0]
+
+
+def packed_pair_sets_from_band(part: dict, fields=("mask", "match")
+                               ) -> Tuple[np.ndarray, ...]:
+    """The packed pair set of each band in ``fields``, from one slot map.
+
+    The slot map holds, per band slot (s, d-1, i), the packed pair of
+    ``eid[s, i]`` and ``eid[s, i+d]``; each set is the map compressed by
+    its band, sorted in place and deduplicated (``_sort_unique``).  The map
+    covers the columns up to the last one that any of the bands selects,
+    so trailing padding costs nothing.  Under a tracer ``collect.slots``
+    adds the map's entries."""
     eid = np.asarray(_eid(part))                          # (r, M)
-    band = np.asarray(part[field])                        # (r, w-1, M)
-    ss, ds, iis = np.nonzero(band)
-    if ss.size == 0:
+    bands = [np.asarray(part[f]) for f in fields]         # (r, w-1, M) each
+    r, k, m = bands[0].shape
+    used = np.zeros(m, bool)
+    for b in bands:
+        used |= b.reshape(-1, m).any(axis=0)
+    sel = np.flatnonzero(used)
+    c = int(sel[-1]) + 1 if sel.size else 0
+    if any(b[:, d - 1, max(0, m - d):c].any() for b in bands
+           for d in range(max(1, m - c + 1), k + 1)):
+        raise IndexError("band selects a slot past its shard's last row")
+    slots = _slot_map(eid, k, c)
+    _count(_SLOTS, slots.size)
+    return tuple(_sort_unique(slots[b[:, :, :c]]) for b in bands)
+
+
+def _slot_map(eid: np.ndarray, k: int, c: int) -> np.ndarray:
+    """(r, k, c) packed pair of ``eid[s, i]`` and ``eid[s, i+d]`` per band
+    slot (s, d-1, i).  ``min(a << 32 | b, b << 32 | a)`` is
+    ``pack_pairs(a, b)`` for eids below 2^32.  Slots past the shard's end
+    (i + d >= M) are left unwritten, and padding eids (-1) give garbage:
+    no band selects either."""
+    r, m = eid.shape
+    e = eid[:, :min(m, c + k)].astype(PACKED_DTYPE)
+    eh = e << PACKED_DTYPE(32)
+    slots = np.empty((r, k, c), PACKED_DTYPE)
+    tmp = np.empty((r, c), PACKED_DTYPE)
+    for d in range(1, k + 1):
+        n = max(0, min(c, m - d))
+        row, t = slots[:, d - 1, :n], tmp[:, :n]
+        np.bitwise_or(eh[:, :n], e[:, d:d + n], out=row)
+        np.bitwise_or(eh[:, d:d + n], e[:, :n], out=t)
+        np.minimum(row, t, out=row)
+    return slots
+
+
+def _sort_unique(a: np.ndarray) -> np.ndarray:
+    """Sort the fresh packed array ``a`` in place and drop repeats (under a
+    tracer ``collect.duplicates`` adds how many)."""
+    a.sort()
+    rep = a[1:] == a[:-1]
+    n = int(np.count_nonzero(rep))
+    _count(_DUPLICATES, n)
+    if n:
+        a = a[np.concatenate(([True], ~rep))]
+    return a
+
+
+def union_packed(arrays) -> np.ndarray:
+    """Union of sorted deduplicated packed arrays: the one array itself
+    when there is one, else concatenated, sorted and deduplicated."""
+    if len(arrays) == 1:
+        return arrays[0]
+    if not arrays:
         return np.empty((0,), PACKED_DTYPE)
-    a = eid[ss, iis]
-    b = eid[ss, iis + ds + 1]       # in-bounds: masks force i + d < M
-    return np.unique(pack_pairs(a, b))
+    return _sort_unique(np.concatenate(arrays))
 
 
 def pairs_from_band(part: dict, field: str = "match") -> Set[Pair]:

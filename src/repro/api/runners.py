@@ -117,9 +117,9 @@ class PackedOutcome(NamedTuple):
 
     This is the collection hot path for callers that aggregate MANY runner
     invocations — ``repro.stream`` unions one of these per chunk with a
-    single ``np.unique`` at the end, instead of materializing a Python
-    pair set per chunk.  ``to_outcome()`` wraps the arrays in the public
-    ``PairSet`` form."""
+    single sort at the end (``results.union_packed``), instead of
+    materializing a Python pair set per chunk.  ``to_outcome()`` wraps the
+    arrays in the public ``PairSet`` form."""
     blocked: "np.ndarray"
     matched: "np.ndarray"
     load: Tuple[int, ...]

@@ -9,7 +9,7 @@ Each variant owns three hooks:
     ``boundary``).  ``cap_link`` is the planner-provided shuffle capacity
     (repro.balance ShardPlan); None derives it from ``cfg.cap_factor``.
   * ``collect(out)``  turn the stacked runner output into host pair sets
-    (blocked + matched), deduplicating across parts
+    (blocked + matched), one slot map per band part, unioned across parts
   * ``sequential_pairs(keys, eids, bounds, w, part=None)``  the HOST oracle
     with this variant's semantics (SRP: per-partition windows — boundary
     pairs are missed by design; RepSN/JobSN: the complete sequential SN
@@ -168,19 +168,18 @@ class VariantBase:
 
     def collect(self, out: dict) -> RES.CollectedPairs:
         """Stacked runner output -> deduplicated PACKED pair arrays (uint64
-        ``(lo << 32) | hi``).  Parts are unioned via np.unique, so a pair
-        emitted by several parts/shards counts once; the RunnerOutcome
-        boundary wraps the arrays in ``results.PairSet``s.  Device-emitted parts (emit="pairs")
-        and band parts are consumed transparently
-        (``results.packed_pairs_from_part``)."""
-        blocked = [RES.packed_pairs_from_part(out[p], "mask")
-                   for p in self.parts if p in out]
-        matched = [RES.packed_pairs_from_part(out[p], "match")
-                   for p in self.parts if p in out]
-        dedup = lambda parts: np.unique(np.concatenate(parts)) if parts \
-            else np.empty((0,), RES.PACKED_DTYPE)
-        return RES.CollectedPairs(blocked=dedup(blocked),
-                                  matched=dedup(matched))
+        ``(lo << 32) | hi``); the RunnerOutcome boundary wraps them in
+        ``results.PairSet``s.  Each part gives both sets at once
+        (``results.packed_pair_sets_from_part``: one slot map for its
+        bands, or its device-emitted index buffers under emit="pairs").  A
+        variant with one part returns that part's arrays as they are;
+        several parts are unioned (``results.union_packed``), so a pair
+        emitted by several parts counts once."""
+        sets = [RES.packed_pair_sets_from_part(out[p])
+                for p in self.parts if p in out]
+        return RES.CollectedPairs(
+            blocked=RES.union_packed([b for b, _ in sets]),
+            matched=RES.union_packed([m for _, m in sets]))
 
     def sequential_pairs(self, keys: np.ndarray, eids: np.ndarray,
                          bounds: np.ndarray, w: int,

@@ -232,11 +232,8 @@ class DeltaMatcher:
             bparts.append(po.blocked)
             mparts.append(po.matched)
             shapes.append((r_b, cap_b))
-        blocked = np.unique(np.concatenate(bparts)) if len(bparts) > 1 \
-            else bparts[0]
-        matched = np.unique(np.concatenate(mparts)) if len(mparts) > 1 \
-            else mparts[0]
-        return blocked, matched, len(shapes), tuple(shapes)
+        return (RES.union_packed(bparts), RES.union_packed(mparts),
+                len(shapes), tuple(shapes))
 
     # -- degraded (brownout) path -------------------------------------------
 

@@ -481,10 +481,8 @@ def _stream_pass_body(raw: ChunkStore, cfg: ERConfig, spec,
                 if fault is not None:
                     fault.after_commit(label, ci)
 
-    dedup = lambda parts: np.unique(np.concatenate(parts)) if parts \
-        else np.empty((0,), RES.PACKED_DTYPE)
-    blocked = dedup(blocked_parts)
-    matched = dedup(matched_parts)
+    blocked = RES.union_packed(blocked_parts)
+    matched = RES.union_packed(matched_parts)
     blocking = BlockingResult(
         pairs=RES.packed_to_pair_set(blocked),
         load=tuple(int(x) for x in load_max), overflow=overflow,
